@@ -1,24 +1,16 @@
-"""Version-compat shim over jax/Pallas API drift.
+"""Backend selection and compiler params shared by the kernel packages.
 
-The kernel packages target two axes of variation:
-
-* **Compiler-params naming.**  ``pltpu.TPUCompilerParams`` (jax <= 0.5.x)
-  was renamed to ``pltpu.CompilerParams`` in later releases; only one of the
-  two exists in any given jax.  ``compiler_params()`` resolves whichever
-  class the installed jax provides, so the kernels never name either class
-  directly.
-* **Backend selection.**  The kernels are written for the TPU Mosaic
-  backend but every wrapper accepts ``interpret``; ``resolve_interpret``
-  maps the default (``None``) to "compiled on TPU, interpreter everywhere
-  else", which is what lets the same serving path run on CI CPUs and on
-  real hardware without configuration.
+The kernels are written for the TPU Mosaic backend, and every wrapper
+accepts ``interpret``.  ``resolve_interpret`` maps the default (``None``) to
+"compiled on TPU, interpreter on CPU"; any other backend is an error rather
+than a silent fall back to the interpreter, so a run that meant to use the
+chip cannot quietly measure the Pallas interpreter instead.
 
 All four kernel packages (``flash_attention``, ``moe_gmm``, ``prefix_scan``,
 ``wkv6``) route through this module; new kernels should too.
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
@@ -26,39 +18,33 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["compiler_params", "resolve_interpret", "has_tpu"]
 
-# Exactly one of the two names exists per jax release.
-_COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams", None)
 
-
-def compiler_params(*, dimension_semantics=None, **kw):
-    """Build TPU compiler params under whichever name this jax exposes.
-
-    Returns ``None`` (pallas_call accepts it) if neither class exists, so a
-    future rename degrades to default compiler behavior instead of an
-    ``AttributeError`` at import time.
-    """
-    if _COMPILER_PARAMS_CLS is None:
-        return None
+def compiler_params(*, dimension_semantics=None, **kw) -> pltpu.CompilerParams:
+    """TPU compiler params; ``dimension_semantics`` as a tuple."""
     if dimension_semantics is not None:
         kw["dimension_semantics"] = tuple(dimension_semantics)
-    return _COMPILER_PARAMS_CLS(**kw)
+    return pltpu.CompilerParams(**kw)
 
 
-@functools.cache
 def has_tpu() -> bool:
-    try:
-        return any(d.platform == "tpu" for d in jax.devices())
-    except RuntimeError:
-        return False
+    """True when JAX's default backend is the TPU.  A backend that fails to
+    initialise raises here instead of reading as "no TPU"."""
+    return jax.default_backend() == "tpu"
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:
-    """``None`` → auto: compiled on TPU, interpreter mode elsewhere.
+    """``None`` → compiled on TPU, interpreter mode on CPU, error elsewhere.
 
     Explicit ``True``/``False`` is honored as-is (tests force the
-    interpreter; a TPU perf run may force compilation).
-    """
-    if interpret is None:
-        return not has_tpu()
-    return bool(interpret)
+    interpreter; a compile rehearsal for a described TPU forces
+    compilation)."""
+    if interpret is not None:
+        return bool(interpret)
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas TPU kernels have no path on backend "
+                       f"{backend!r}: run on a TPU, or on the CPU in "
+                       f"interpret mode")

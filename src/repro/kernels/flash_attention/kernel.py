@@ -17,7 +17,9 @@ Masking knobs (all composable):
   are always masked.
 * ``kv_valid`` — optional per-batch *dynamic* valid-kv count ``[B]``.  This
   is the single-token decode path over a partially-filled (or ring-wrapped)
-  cache: slots ``>= kv_valid[b]`` are masked for that sequence only.
+  cache: slots ``>= kv_valid[b]`` are masked for that sequence only.  The
+  counts are scalar-prefetched into SMEM (a ``[B]`` vector cannot be tiled
+  into VMEM blocks one element wide).
 
 Forward only: the training path uses XLA attention (or this kernel under
 ``jax.checkpoint`` recomputation); serving uses it directly — prefill via
@@ -40,8 +42,9 @@ __all__ = ["flash_attention_pallas"]
 _NEG = -1e30
 
 
-def _kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, acc_ref, m_ref, l_ref, *,
+def _kernel(valid_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
             scale, causal, window, bq, bk, kv_len, q_offset):
+    bi = pl.program_id(0)
     qi = pl.program_id(2)
     ki = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -73,7 +76,7 @@ def _kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, acc_ref, m_ref, l_ref, *,
                                 preferred_element_type=jnp.float32) * scale
         rows = q_lo + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         cols = k_lo + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        mask = cols < jnp.minimum(kv_len, valid_ref[0, 0])
+        mask = cols < jnp.minimum(kv_len, valid_ref[bi])
         if causal:
             mask &= cols <= rows
         if window is not None:
@@ -119,31 +122,33 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         scale = d ** -0.5
     if kv_valid is None:
         kv_valid = jnp.full((b,), t, jnp.int32)
-    valid = kv_valid.astype(jnp.int32).reshape(b, 1)
-    grid = (b, h, s // bq, t // bk)
-    return pl.pallas_call(
-        functools.partial(_kernel, scale=scale, causal=causal,
-                          window=window, bq=bq, bk=bk,
-                          kv_len=kv_len or t, q_offset=q_offset),
-        grid=grid,
+    # index maps receive the scalar-prefetch ref as a trailing argument
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, h, s // bq, t // bk),
         in_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
+            pl.BlockSpec((1, 1, bq, d),
+                         lambda b_, h_, qi, ki, _: (b_, h_, qi, 0)),
             pl.BlockSpec((1, 1, bk, d),
-                         lambda b_, h_, qi, ki: (b_, h_ // group, ki, 0)),
+                         lambda b_, h_, qi, ki, _: (b_, h_ // group, ki, 0)),
             pl.BlockSpec((1, 1, bk, d),
-                         lambda b_, h_, qi, ki: (b_, h_ // group, ki, 0)),
-            pl.BlockSpec((1, 1), lambda b_, h_, qi, ki: (b_, 0)),
+                         lambda b_, h_, qi, ki, _: (b_, h_ // group, ki, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, d),
-                               lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
+                               lambda b_, h_, qi, ki, _: (b_, h_, qi, 0)),
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
-        ],
+        ])
+    return pl.pallas_call(
+        functools.partial(_kernel, scale=scale, causal=causal,
+                          window=window, bq=bq, bk=bk,
+                          kv_len=kv_len or t, q_offset=q_offset),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
         compiler_params=compiler_params(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=resolve_interpret(interpret),
-    )(q, k, v, valid)
+    )(kv_valid.astype(jnp.int32), q, k, v)

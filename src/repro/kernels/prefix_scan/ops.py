@@ -16,12 +16,14 @@ __all__ = ["prefix_scan"]
 def prefix_scan(x: jax.Array, *, block: int = 256,
                 interpret: Optional[bool] = None) -> jax.Array:
     """Inclusive prefix sum along the last axis; any rank ≥ 1; pads the
-    last axis to a block multiple internally."""
+    last axis to a block multiple and the rows to a multiple of 8
+    internally."""
     shape = x.shape
     n = shape[-1]
     x2 = x.reshape(-1, n)
-    pad = (-n) % block
-    if pad:
-        x2 = jnp.pad(x2, ((0, 0), (0, pad)))
+    rows = x2.shape[0]
+    pad_r, pad = (-rows) % 8, (-n) % block
+    if pad_r or pad:
+        x2 = jnp.pad(x2, ((0, pad_r), (0, pad)))
     y = prefix_scan_pallas(x2, block=block, interpret=interpret)
-    return y[:, :n].reshape(shape)
+    return y[:rows, :n].reshape(shape)

@@ -6,7 +6,8 @@ the HBM traffic is O(T·N) for the ios instead of O(T·N²) for materialized
 states, and the sequential chunk walk is the same carry pattern as the
 prefix-scan kernel (the paper's one-pass strategy).  Within a chunk the
 recurrence is stepped with a ``fori_loop`` over VPU outer-products (the MXU
-has no use here: the state update is rank-1).
+has no use here: the state update is rank-1); the column operands of those
+products are built by a diagonal mask-and-reduce, not a transpose.
 
 Forward only (serving/prefill path; training uses the chunked associative
 scan in ``models/ssm.py``, which this kernel is verified against).
@@ -28,6 +29,12 @@ from ..compat import compiler_params, resolve_interpret
 __all__ = ["wkv6_pallas"]
 
 
+def _column(row: jax.Array, eye: jax.Array) -> jax.Array:
+    """[1, N] row -> [N, 1] column without a transpose: mask the broadcast
+    row to its diagonal and reduce over lanes (exact, VPU/XLU only)."""
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, s_out_ref,
             s_ref, *, chunk):
     ti = pl.program_id(2)
@@ -37,26 +44,23 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, s_out_ref,
     def _init():
         s_ref[...] = s0_ref[0, 0]
 
-    u = u_ref[0].astype(jnp.float32)                  # [N]
-    r = r_ref[0, :, 0].astype(jnp.float32)            # [c, N]
-    k = k_ref[0, :, 0].astype(jnp.float32)
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    w = w_ref[0, :, 0].astype(jnp.float32)
+    n = s_ref.shape[0]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+    u = u_ref[0]                                      # [1, N]
 
-    def step(i, carry):
-        s, ys = carry
-        ri, ki, vi, wi = r[i], k[i], v[i], w[i]
-        kv = ki[:, None] * vi[None, :]                # [N, N]
-        y = ri @ s + (ri * u * ki).sum() * vi         # [N]
-        ys = jax.lax.dynamic_update_slice_in_dim(ys, y[None], i, axis=0)
-        s = wi[:, None] * s + kv
-        return s, ys
+    def step(i, s):                                   # s: [N(k), N(v)]
+        row = pl.ds(i, 1)
+        ri = r_ref[0, 0, row, :]                      # [1, N]
+        ki = k_ref[0, 0, row, :]
+        vi = v_ref[0, 0, row, :]
+        wi = w_ref[0, 0, row, :]
+        bonus = jnp.sum(ri * u * ki, axis=1, keepdims=True)       # [1, 1]
+        y_ref[0, 0, row, :] = (jnp.sum(_column(ri, eye) * s, axis=0,
+                                       keepdims=True) + bonus * vi)
+        return _column(wi, eye) * s + _column(ki, eye) * vi
 
-    s0 = s_ref[...]
-    ys0 = jnp.zeros((chunk, r.shape[-1]), jnp.float32)
-    s_end, ys = jax.lax.fori_loop(0, chunk, step, (s0, ys0))
-    s_ref[...] = s_end
-    y_ref[0, :, 0] = ys.astype(y_ref.dtype)
+    s_ref[...] = jax.lax.fori_loop(0, chunk, step, s_ref[...])
 
     @pl.when(ti == nt - 1)
     def _flush_state():
@@ -74,23 +78,31 @@ def wkv6_pallas(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
     assert t % chunk == 0, (t, chunk)
     if s0 is None:
         s0 = jnp.zeros((b, h, n, n), jnp.float32)
+
+    # kernel layout [B, H, T, N] in fp32: a block's last two dims are then
+    # (chunk, N), which the TPU tiles; (1, N) slices of [B, T, H, N] it
+    # cannot.  Rows are read one step at a time, which fp32 keeps unpacked.
+    def heads_major(a):
+        return jnp.swapaxes(a, 1, 2).astype(jnp.float32)
+
     grid = (b, h, t // chunk)
-    io_spec = pl.BlockSpec((1, chunk, 1, n),
-                           lambda b_, h_, ti: (b_, ti, h_, 0))
+    io_spec = pl.BlockSpec((1, 1, chunk, n),
+                           lambda b_, h_, ti: (b_, h_, ti, 0))
     state_spec = pl.BlockSpec((1, 1, n, n),
                               lambda b_, h_, ti: (b_, h_, 0, 0))
     y, s_end = pl.pallas_call(
         functools.partial(_kernel, chunk=chunk),
         grid=grid,
         in_specs=[io_spec, io_spec, io_spec, io_spec,
-                  pl.BlockSpec((1, n), lambda b_, h_, ti: (h_, 0)),
+                  pl.BlockSpec((1, 1, n), lambda b_, h_, ti: (h_, 0, 0)),
                   state_spec],
         out_specs=[io_spec, state_spec],
-        out_shape=[jax.ShapeDtypeStruct((b, t, h, n), r.dtype),
+        out_shape=[jax.ShapeDtypeStruct((b, h, t, n), jnp.float32),
                    jax.ShapeDtypeStruct((b, h, n, n), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
         compiler_params=compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=resolve_interpret(interpret),
-    )(r, k, v, w, u, s0.astype(jnp.float32))
-    return y, s_end
+    )(heads_major(r), heads_major(k), heads_major(v), heads_major(w),
+      u.astype(jnp.float32).reshape(h, 1, n), s0.astype(jnp.float32))
+    return jnp.swapaxes(y, 1, 2).astype(r.dtype), s_end

@@ -20,6 +20,11 @@ finishes and replayed counts match telemetry — see docs/operations.md):
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --smoke \
         --replicas 2 --requests 12 --chaos kill-one
+
+Full width on a TPU (what ``chip_smoke.py`` drives): drop ``--smoke``.
+Replica ``i`` of a cluster run holds its params and KV pool on
+``jax.devices()[i % device_count]``.  JAX's persistent compilation cache
+is on (``compile_cache.enable_compile_cache``).
 """
 from __future__ import annotations
 
@@ -27,6 +32,7 @@ import argparse
 import sys
 import time
 from collections import Counter
+from typing import List, NamedTuple, Optional, Sequence
 
 import jax
 import numpy as np
@@ -35,22 +41,40 @@ from ..cluster import (ClusterRouter, ClusterTelemetry, EngineReplica,
                        StealPolicy)
 from ..configs import get_config, scale_down
 from ..core.device.request_scheduler import Request
+from ..kernels.compat import has_tpu
 from ..models import build_model
 from ..runtime import (Autoscaler, AutoscalePolicy, HeartbeatMonitor,
                        StragglerDetector)
 from ..serving import ServingEngine, Speculator
+from .compile_cache import enable_compile_cache
+
+#: tokens of the system prefix that every other prompt shares
+SYS_PREFIX = 16
 
 
-def _make_prompts(args, cfg):
+class ServeResult(NamedTuple):
+    rc: int                            # the driver's exit code
+    tokens: List[List[int]]            # generated tokens, submission order
+    engines: List[ServingEngine]       # one per replica
+
+
+def make_prompts(args, cfg) -> List[np.ndarray]:
     """Mixed traffic: half the prompts share a 16-token system prefix (the
-    shared-prefix caching shape), half are cold."""
+    shared-prefix caching shape), half are cold.  ``--prompt-lens`` fixes
+    the total prompt lengths, cycled over the requests; otherwise the
+    private tails are 4-31 tokens."""
     rng = np.random.default_rng(args.seed)
-    sys_prefix = rng.integers(0, cfg.vocab_size, 16)
+    sys_prefix = rng.integers(0, cfg.vocab_size, SYS_PREFIX)
     out = []
     for i in range(args.requests):
-        tail = rng.integers(0, cfg.vocab_size, int(rng.integers(4, 32)))
-        out.append(np.concatenate([sys_prefix, tail]) if i % 2 == 0
-                   else tail)
+        shared = i % 2 == 0
+        if args.prompt_lens:
+            n = args.prompt_lens[i % len(args.prompt_lens)] \
+                - (SYS_PREFIX if shared else 0)
+        else:
+            n = int(rng.integers(4, 32))
+        tail = rng.integers(0, cfg.vocab_size, n)
+        out.append(np.concatenate([sys_prefix, tail]) if shared else tail)
     return out
 
 
@@ -113,6 +137,7 @@ def _make_spec(args, draft) -> "Speculator | None":
 
 
 def _run_engine(eng, prompts, args):
+    """Submit ``prompts`` (SLO class ``i % 3``) and drain the engine."""
     reqs = [eng.submit(p, max_new_tokens=args.max_new_tokens,
                        priority=float(i % 3))
             for i, p in enumerate(prompts)]
@@ -120,17 +145,19 @@ def _run_engine(eng, prompts, args):
     return reqs, outs
 
 
-def _serve_single(args, model, params, cfg, draft=None) -> None:
+def serve_single(args, model, params, cfg, draft=None) -> ServeResult:
+    """One engine serves ``make_prompts``; exit code 1 unless every request
+    finished."""
     eng = ServingEngine(model, params, speculator=_make_spec(args, draft),
                         **_engine_kw(args))
     t0 = time.perf_counter()
-    reqs, outs = _run_engine(eng, _make_prompts(args, cfg), args)
+    reqs, outs = _run_engine(eng, make_prompts(args, cfg), args)
     dt = time.perf_counter() - t0
     done = sum(1 for r in reqs if r.state.name == "DONE")
     toks = sum(len(outs[r.rid]) for r in reqs)
     m = eng.batcher.metrics
     print(f"completed {done}/{len(reqs)} requests, {toks} tokens in "
-          f"{dt:.2f}s ({toks / dt:.1f} tok/s) [kv={eng.kv_mode}]")
+          f"{dt:.2f}s host wall clock, compiles included [kv={eng.kv_mode}]")
     print(f"scheduler: steps={m['steps']} merged_prefills="
           f"{m['merged_prefills']} prefill_chunks={m['prefill_chunks']} "
           f"evicted_dead={m['evicted_dead']} preempted={m['preempted']}")
@@ -153,16 +180,22 @@ def _serve_single(args, model, params, cfg, draft=None) -> None:
               f"acceptance={s['acceptance_rate']:.2f} "
               f"merged_drafts={s['merged_drafts']} shed={s['shed']} "
               f"verify_calls={s['verify_calls']}")
+    rc = 0
+    if done != len(reqs):
+        print(f"FAIL: {len(reqs) - done}/{len(reqs)} requests did not "
+              f"finish", file=sys.stderr)
+        rc = 1
+    return ServeResult(rc, [outs[r.rid] for r in reqs], [eng])
 
 
-def _check_paged_equality(args, model, params, cfg, draft=None) -> int:
+def check_paged_equality(args, model, params, cfg, draft=None) -> int:
     """CI gate: the paged engine must generate exactly what the contiguous
     engine generates (fp32 bit-identical; bf16 identical in practice since
     the gathered logical views match the dense cache bit-for-bit).  Also
     runs the chunked-prefill paged engine — numerics-gated: every request
     must finish with the same token count, and token mismatches (argmax
     tie flips at chunk boundaries) are reported."""
-    prompts = _make_prompts(args, cfg)
+    prompts = make_prompts(args, cfg)
     results = {}
     cache_eng = None
     modes = [
@@ -258,14 +291,19 @@ def _check_paged_equality(args, model, params, cfg, draft=None) -> int:
     return 0
 
 
-def _serve_cluster(args, model, params, cfg, draft=None) -> int:
-    def make_engine():
+def serve_cluster(args, model, params, cfg, draft=None) -> ServeResult:
+    """``--replicas`` engines behind a ``ClusterRouter`` serve
+    ``make_prompts``; replica ``i`` lives on ``jax.devices()[i % n]``."""
+    devices = jax.devices()
+
+    def make_engine(i):
         return ServingEngine(model, params,
                              speculator=_make_spec(args, draft),
+                             device=devices[i % len(devices)],
                              **_engine_kw(args))
 
     chaotic = args.chaos is not None or args.autoscale
-    replicas = [EngineReplica(i, make_engine())
+    replicas = [EngineReplica(i, make_engine(i))
                 for i in range(args.replicas)]
     policy = StealPolicy(amount=args.steal, placement=args.placement)
     # Chaos/autoscale runs get liveness + speed tracking: a killed engine
@@ -285,11 +323,9 @@ def _serve_cluster(args, model, params, cfg, draft=None) -> int:
             min_replicas=args.replicas, max_replicas=ceiling,
             target_backlog=args.autoscale_target,
             up_ticks=2, down_ticks=8, cooldown_s=0.5))
-    rng = np.random.default_rng(args.seed)
     t0 = time.perf_counter()
     reqs = []
-    for i in range(args.requests):
-        prompt = rng.integers(0, cfg.vocab_size, int(rng.integers(4, 48)))
+    for i, prompt in enumerate(make_prompts(args, cfg)):
         req = Request(prompt_len=len(prompt),
                       max_new_tokens=args.max_new_tokens,
                       priority=float(i % 3))
@@ -325,9 +361,9 @@ def _serve_cluster(args, model, params, cfg, draft=None) -> int:
                                            backlog)
                 if delta > 0:
                     for _ in range(delta):
-                        idx = router.add_replica(
-                            EngineReplica(len(router.replicas),
-                                          make_engine()))
+                        idx = len(router.replicas)
+                        router.add_replica(
+                            EngineReplica(idx, make_engine(idx)))
                         print(f"[autoscale] added replica {idx}")
                     tel.record_scale(time.perf_counter() - t0, delta,
                                      len(router.placeable))
@@ -345,13 +381,14 @@ def _serve_cluster(args, model, params, cfg, draft=None) -> int:
         else:
             print("FAIL: cluster did not drain within step budget",
                   file=sys.stderr)
-            return 1
+            return ServeResult(1, [], [])
 
     dt = time.perf_counter() - t0
     done = sum(1 for r in reqs if r.state.name == "DONE")
     toks = sum(r.generated for r in reqs)
     print(f"completed {done}/{len(reqs)} requests, {toks} tokens in "
-          f"{dt:.2f}s ({toks / dt:.1f} tok/s) on {args.replicas} replicas")
+          f"{dt:.2f}s host wall clock, compiles included, on "
+          f"{args.replicas} replicas")
     tel = router.telemetry
     print(tel.report())
     summary = tel.summary()
@@ -380,15 +417,16 @@ def _serve_cluster(args, model, params, cfg, draft=None) -> int:
               + (f" free_kv={h['free_kv_tokens']}"
                  if "free_kv_tokens" in h else ""))
 
-    # Chaos acceptance gates: every request reaches a terminal state with
-    # nothing silently lost, replayed counts match what telemetry recorded
-    # at each crash, and per-SLO-class telemetry accounts for every finish.
+    # Acceptance gates: every request reaches a terminal state with nothing
+    # silently lost; under chaos, replayed counts match what telemetry
+    # recorded at each crash, and per-SLO-class telemetry accounts for
+    # every finish.
+    ok = True
+    if done != len(submitted):
+        print(f"FAIL: {len(submitted) - done} submitted requests did "
+              f"not finish", file=sys.stderr)
+        ok = False
     if chaotic:
-        ok = True
-        if done != len(submitted):
-            print(f"FAIL: {len(submitted) - done} submitted requests did "
-                  f"not finish", file=sys.stderr)
-            ok = False
         if args.chaos == "kill-one":
             if killed is None:
                 print("FAIL: chaos kill never triggered", file=sys.stderr)
@@ -414,18 +452,32 @@ def _serve_cluster(args, model, params, cfg, draft=None) -> int:
         if ok:
             print(f"OK: chaos/autoscale smoke — {done}/{len(submitted)} "
                   f"finished, replay bookkeeping consistent")
-        return 0 if ok else 1
-    return 0
+    # a stolen request's tokens live on the engine that finished it; a dead
+    # engine's copies are stale (its requests replayed elsewhere)
+    outs = {}
+    for rep in router.replicas:
+        if not rep.dead:
+            outs.update(rep.engine.outputs)
+    return ServeResult(0 if ok else 1, [outs.get(r.rid, []) for r in reqs],
+                       [rep.engine for rep in router.replicas])
 
 
-def main() -> int:
-    ap = argparse.ArgumentParser()
+def _int_list(text: str) -> List[int]:
+    return [int(x) for x in text.split(",") if x]
+
+
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
+    ap = argparse.ArgumentParser(prog="repro.launch.serve")
     ap.add_argument("--arch", default="qwen2-1.5b")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--replicas", type=int, default=1)
     ap.add_argument("--max-batch", type=int, default=4)
     ap.add_argument("--s-max", type=int, default=128)
     ap.add_argument("--max-new-tokens", type=int, default=16)
+    ap.add_argument("--prompt-lens", type=_int_list, default=None,
+                    help="comma-separated total prompt lengths, cycled "
+                         "over the requests (default: 4-31 token tails, "
+                         "every other one behind a 16-token shared prefix)")
     ap.add_argument("--steal", default="half_work",
                     choices=["half_work", "half_count", "none"])
     ap.add_argument("--placement", default="round_robin",
@@ -498,32 +550,45 @@ def main() -> int:
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     # Pallas kernels on the hot path: flash prefill/decode + grouped-matmul
-    # MoE + WKV.  Default auto = compiled kernels on TPU, XLA elsewhere
-    # (the CPU interpreter validates the path but is far slower than XLA);
-    # force with --use-flash (CI/smoke) or --no-use-flash.
+    # MoE + WKV.  Default: compiled kernels when JAX's backend is the TPU,
+    # XLA otherwise; --use-flash forces the kernels (interpret mode on the
+    # CPU, which validates the path but is far slower than XLA) and
+    # --no-use-flash forces XLA.
     ap.add_argument("--use-flash", dest="use_flash", action="store_true",
                     default=None)
     ap.add_argument("--no-use-flash", dest="use_flash", action="store_false")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    if args.prompt_lens and min(args.prompt_lens) <= SYS_PREFIX:
+        ap.error(f"--prompt-lens must exceed the {SYS_PREFIX}-token shared "
+                 f"prefix")
+    return args
 
+
+def build(args) -> tuple:
+    """``(cfg, model, params)`` for ``args``: the published config (or its
+    ``--smoke`` cut), kernels per ``--use-flash``, params from ``--seed``."""
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = scale_down(cfg, layers=4, d_model=256, d_ff=1024,
                          vocab=min(cfg.vocab_size, 32768))
-    if args.use_flash is None:
-        from ..kernels.compat import has_tpu
-        cfg = cfg.replace(use_flash=has_tpu())
-    else:
-        cfg = cfg.replace(use_flash=args.use_flash)
+    use_flash = has_tpu() if args.use_flash is None else args.use_flash
+    cfg = cfg.replace(use_flash=use_flash)
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed))
+    # one compiled program: op-by-op init of a full-width model is slow
+    params = jax.jit(model.init)(jax.random.PRNGKey(args.seed))
+    return cfg, model, params
+
+
+def main(argv: Optional[Sequence[str]] = None) -> int:
+    args = parse_args(argv)
+    enable_compile_cache()
+    cfg, model, params = build(args)
     draft = _build_draft(args, model, params, cfg)
     if args.check_paged_equality:
-        return _check_paged_equality(args, model, params, cfg, draft)
+        return check_paged_equality(args, model, params, cfg, draft)
     if args.replicas > 1:
-        return _serve_cluster(args, model, params, cfg, draft)
-    _serve_single(args, model, params, cfg, draft)
-    return 0
+        return serve_cluster(args, model, params, cfg, draft).rc
+    return serve_single(args, model, params, cfg, draft).rc
 
 
 if __name__ == "__main__":

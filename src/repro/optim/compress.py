@@ -12,10 +12,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-try:                                    # jax >= 0.6 exports it at top level
-    from jax import shard_map
-except ImportError:                     # older jax: experimental namespace
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["error_feedback_compress", "compressed_allreduce"]
@@ -51,12 +48,7 @@ def compressed_allreduce(grads, error, mesh, axis: str = "pod"):
             lambda g: (jax.lax.psum(g.astype(jnp.bfloat16), axis)
                        / mesh.shape[axis]).astype(jnp.float32), tree)
 
-    # replication checking is named check_vma on new jax, check_rep before
-    try:
-        mapped = shard_map(reduce_fn, mesh=mesh, in_specs=(specs,),
-                           out_specs=specs, check_vma=False)
-    except TypeError:
-        mapped = shard_map(reduce_fn, mesh=mesh, in_specs=(specs,),
-                           out_specs=specs, check_rep=False)
+    mapped = shard_map(reduce_fn, mesh=mesh, in_specs=(specs,),
+                       out_specs=specs, check_vma=False)
     reduced = mapped(comp)
     return reduced, new_err

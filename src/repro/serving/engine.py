@@ -56,7 +56,8 @@ class ServingEngine:
                  admission: str = "strategy",
                  prefix_cache: bool = False,
                  overflow: str = "reject",
-                 speculator: Optional[Speculator] = None):
+                 speculator: Optional[Speculator] = None,
+                 device: Optional[jax.Device] = None):
         if kv_mode not in ("auto", "paged", "contiguous"):
             raise ValueError(f"unknown kv_mode {kv_mode!r}")
         if overflow not in ("reject", "truncate", "allow"):
@@ -66,6 +67,12 @@ class ServingEngine:
                 f"family {model.cfg.family!r} has no paged decode path")
         if kv_mode == "auto":
             kv_mode = "paged" if model.supports_paged else "contiguous"
+        #: device holding this engine's params and KV pool (None = JAX's
+        #: default device); the jitted steps run where their committed
+        #: inputs live, so replicas pinned to different chips never meet
+        self.device = device
+        if device is not None:
+            params = jax.device_put(params, device)
         self.model = model
         self.params = params
         self.s_max = s_max
@@ -92,8 +99,10 @@ class ServingEngine:
         #: see them separately (else a plan whose members jointly hold the
         #: whole pool deadlocks: everyone defers to invisible holders)
         self._pending_prefill: List[Request] = []
-        # jit per distinct prompt length (lengths repeat across requests)
-        self._prefill = jax.jit(lambda p, b: model.prefill(p, b, s_max))
+        # jit per distinct prompt length (lengths repeat across requests);
+        # jitting the model's own function lets engines that share a model
+        # share its compiled programs
+        self._prefill = jax.jit(model.prefill, static_argnums=2)
         self._prefill_chunk = None
         cfg = model.cfg
         #: ring capacity of the KV cache (window-clamped); SSM families have
@@ -141,8 +150,9 @@ class ServingEngine:
                 raise ValueError("pool smaller than one full ring: "
                                  f"{num_blocks - 1} < {self.max_blocks}")
             self.alloc = BlockAllocator(num_blocks, block_size)
-            self.cache = model.init_paged_cache(max_batch, num_blocks,
-                                                block_size)
+            with jax.default_device(device):
+                self.cache = model.init_paged_cache(max_batch, num_blocks,
+                                                    block_size)
             self.table = np.full((max_batch, self.max_blocks), SINK_BLOCK,
                                  np.int32)
             # device-side table cache: re-uploaded only when the allocator
@@ -162,7 +172,8 @@ class ServingEngine:
             self.batcher.chunk_eligible = _chunk_eligible
             self.batcher.on_request_pruned = self._on_pruned
         else:
-            self.cache = model.init_cache(max_batch, s_max)
+            with jax.default_device(device):
+                self.cache = model.init_cache(max_batch, s_max)
             self._decode = jax.jit(model.decode_step)
             self._insert = (jax.jit(model.insert_prefill)
                             if model.insert_prefill is not None else None)
@@ -697,7 +708,7 @@ class ServingEngine:
         else:
             toks = self.prompts[rid][None, :]
             logits, cache_one = self._prefill(
-                self.params, {"tokens": jnp.asarray(toks)})
+                self.params, {"tokens": jnp.asarray(toks)}, self.s_max)
             if self.paged:
                 # scatter the dense per-request cache into its blocks
                 row = jnp.asarray(self._table_row(rid))

@@ -202,3 +202,21 @@ def test_wkv6_kernel_matches_train_path():
                                atol=1e-3)
     np.testing.assert_allclose(np.asarray(s_kernel), np.asarray(s_assoc),
                                atol=1e-3)
+
+
+# ------------------------------------------------------------ backend policy
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False),
+                                               ("gpu", None)])
+def test_resolve_interpret_follows_backend(monkeypatch, backend, interpret):
+    """The interpreter only on the CPU, compiled Mosaic on the TPU, and an
+    error (never a silent interpreter fallback) anywhere else."""
+    from repro.kernels import compat
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            compat.resolve_interpret(None)
+    else:
+        assert compat.resolve_interpret(None) is interpret
+    assert compat.resolve_interpret(True) is True
+    assert compat.resolve_interpret(False) is False
+    assert compat.has_tpu() is (backend == "tpu")

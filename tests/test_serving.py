@@ -1,4 +1,6 @@
 """Serving engine: continuous batching with per-request strategies."""
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -428,3 +430,45 @@ def test_ssm_family_falls_back_to_contiguous():
     assert eng.kv_mode == "contiguous" and not eng.paged
     with pytest.raises(ValueError):
         ServingEngine(model, params, max_batch=2, s_max=32, kv_mode="paged")
+
+
+def test_serve_driver_exit_code_counts_unfinished_requests(monkeypatch):
+    """launch.serve's single engine exits 0 only when every request is
+    DONE; --prompt-lens fixes the prompt lengths, cycled over requests."""
+    from repro.launch import serve
+    args = serve.parse_args(["--smoke", "--requests", "3",
+                             "--max-new-tokens", "2", "--s-max", "64",
+                             "--prompt-lens", "20,40"])
+    cfg, model, params = serve.build(args)
+    assert [len(p) for p in serve.make_prompts(args, cfg)] == [20, 40, 20]
+    res = serve.serve_single(args, model, params, cfg)
+    assert res.rc == 0 and [len(t) for t in res.tokens] == [2, 2, 2]
+    monkeypatch.setattr(ServingEngine, "run_until_drained",
+                        lambda self, max_steps=0: self.outputs)
+    assert serve.serve_single(args, model, params, cfg).rc == 1
+
+
+def test_engine_keeps_params_and_pool_on_its_device():
+    cfg, model, params, _ = _engine()
+    dev = jax.devices()[-1]
+    eng = ServingEngine(model, params, max_batch=2, s_max=32, device=dev)
+    eng.submit(np.arange(1, 9), max_new_tokens=3)
+    eng.run_until_drained()
+    homes = {d for leaf in jax.tree.leaves((eng.params, eng.cache))
+             for d in leaf.devices()}
+    assert homes == {dev}
+
+
+def test_compile_cache_dir_env_or_fixed_checkout_path(monkeypatch, tmp_path):
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before  # JAX reads it
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = compile_cache.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    assert path == str(Path(__file__).resolve().parents[1] / ".jax_cache")
