@@ -71,6 +71,11 @@ class Request:
     state: RequestState = RequestState.WAITING
     generated: int = 0
     prefilled: int = 0
+    #: the scheduler's clock when a plan first took the request into
+    #: prefill or the running batch (a preempted request keeps it)
+    admitted_at: Optional[float] = None
+    #: the clock once the first token is on the host (stamped by whatever
+    #: executes the request: the engine after its argmax, the simulator)
     first_token_at: Optional[float] = None
     finished_at: Optional[float] = None
     #: prompt tokens covered by the local prefix cache (set by the engine /
@@ -127,6 +132,7 @@ class Request:
         self.prefilled = 0
         self.generated = 0
         self.cached_prefix = 0
+        self.admitted_at = None
         self.first_token_at = None
         self.finished_at = None
         self.spec_k = 0
@@ -291,7 +297,7 @@ class ContinuousBatcher:
                         "deadline_misses": 0, "prefill_chunks": 0,
                         "calls_converted": 0, "preempted": 0,
                         "rejected": 0, "truncated": 0,
-                        "wrapped_oversize": 0}
+                        "wrapped_oversize": 0, "compiles": 0}
         # thieves probe load counters far more often than queues mutate, so
         # the O(queue) scans are cached behind a mutation version stamp
         self._version = 0
@@ -488,6 +494,8 @@ class ContinuousBatcher:
                 req.state = RequestState.RUNNING
                 self.running[req.rid] = req
                 plan.admitted.append(req)
+            if req.admitted_at is None:
+                req.admitted_at = self.now()
         if len(plan.prefill) > 1:
             self.metrics["merged_prefills"] += len(plan.prefill) - 1
         # 3. everyone running decodes one token this step
@@ -511,8 +519,6 @@ class ContinuousBatcher:
             self.submit(request)
             return False
         request.state = RequestState.RUNNING
-        if request.first_token_at is None:
-            request.first_token_at = self.now()
         self.running[request.rid] = request
         self._bump()
         return True

@@ -1,7 +1,7 @@
 """Uniform model interface over all assigned architecture families."""
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, fields
 from typing import Any, Callable, Optional
 
 import jax
@@ -63,6 +63,18 @@ class Model:
 
 
 def build_model(cfg: ModelConfig) -> Model:
+    """The family's model, its functions named after their fields: a
+    program jitted from one traces as ``jit_decode_step_paged`` (not
+    ``jit__lambda``), and engines sharing the model share its programs."""
+    model = _build(cfg)
+    for f in fields(model):
+        fn = getattr(model, f.name)
+        if getattr(fn, "__name__", None) == "<lambda>":
+            fn.__name__ = fn.__qualname__ = f.name
+    return model
+
+
+def _build(cfg: ModelConfig) -> Model:
     fam = cfg.family
     if fam in ("dense", "moe", "vlm"):
         return Model(

@@ -23,15 +23,22 @@ Two KV layouts (``kv_mode``):
 Works with any family whose cache pytree carries the batch on a fixed axis
 (dense/MoE/VLM: axis 1 of [L, B, S, ...]; RWKV: axis 1).  CPU-runnable with
 reduced configs — that is how the examples and tests drive it.
+
+Each phase of :meth:`ServingEngine.step` is a ``jax.profiler`` span named
+``serve.*`` (``serve.step`` > ``plan``, ``prefill``, ``speculate``,
+``blocks``, ``decode``, ``wait``, ``commit``); outside a profiler trace a
+span costs about a microsecond.  ``docs/serving.md`` lists their stats.
 """
 from __future__ import annotations
 
+import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from ..core.device.request_scheduler import (AdmissionRejected, BatchPlan,
                                              ContinuousBatcher, Request,
@@ -43,6 +50,24 @@ from .paged_kv import (BlockAllocator, PoolExhausted, SINK_BLOCK,
 from .speculative import Speculator
 
 __all__ = ["ServingEngine"]
+
+#: JAX reports every program it compiles, or loads from the persistent
+#: cache, with this duration event, on the thread that asked for it
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_log = threading.local()
+
+
+def _compiled() -> int:
+    """Programs compiled or loaded on this thread so far."""
+    return getattr(_compile_log, "n", 0)
+
+
+def _on_duration(event: str, duration: float, **kwargs) -> None:
+    if event == _COMPILE_EVENT:
+        _compile_log.n = _compiled() + 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 class ServingEngine:
@@ -71,6 +96,9 @@ class ServingEngine:
         #: default device); the jitted steps run where their committed
         #: inputs live, so replicas pinned to different chips never meet
         self.device = device
+        #: the ``device`` stat of every ``serve.step`` span
+        self._device_id = (device if device is not None
+                           else jax.devices()[0]).id
         if device is not None:
             params = jax.device_put(params, device)
         self.model = model
@@ -621,10 +649,26 @@ class ServingEngine:
                     # preempted request resumes non-speculatively elsewhere
                     self.speculator.on_clear(i)
 
-    def _insert_contiguous(self, slot: int, cache_one) -> None:
+    def _run(self, span: TraceAnnotation, program, *args):
+        """Call one of the engine's programs inside ``span``.  A call during
+        which JAX compiled or loaded a program counts once in
+        ``metrics["compiles"]`` and marks its span ``compiled=1``."""
+        seen = _compiled()
+        out = program(*args)
+        self._note_compiles(span, seen)
+        return out
+
+    def _note_compiles(self, span: TraceAnnotation, seen: int) -> None:
+        if _compiled() != seen:
+            self.batcher.metrics["compiles"] += 1
+            span.set_metadata(compiled=1)
+
+    def _insert_contiguous(self, span: TraceAnnotation, slot: int,
+                           cache_one) -> None:
         if self._insert is not None:
             # per-leaf batch axes (hybrid: KV axis 1, Mamba states axis 2)
-            self.cache = self._insert(self.cache, cache_one, slot)
+            self.cache = self._run(span, self._insert, self.cache, cache_one,
+                                   slot)
             return
         ax = self.batch_axis
 
@@ -698,26 +742,33 @@ class ServingEngine:
             need = req.prefilled + chunk if chunked else req.prompt_len
             if not self._ensure_blocks(req, need):
                 return self._requeue(req)          # pool full; retry later
-        if chunked:
-            start = req.prefilled
-            toks = self.prompts[rid][start:start + chunk]
-            row = jnp.asarray(self._table_row(rid))
-            logits, self.cache = self._prefill_chunk(
-                self.params, {"tokens": jnp.asarray(toks[None, :])},
-                self.cache, row, jnp.int32(start))
-        else:
-            toks = self.prompts[rid][None, :]
-            logits, cache_one = self._prefill(
-                self.params, {"tokens": jnp.asarray(toks)}, self.s_max)
-            if self.paged:
-                # scatter the dense per-request cache into its blocks
+        # the dense path prefills the whole prompt from position 0
+        start = req.prefilled if chunked else 0
+        with TraceAnnotation("serve.prefill", rid=rid, start=start,
+                             tokens=chunk if chunked else req.prompt_len
+                             ) as span:
+            if chunked:
+                toks = self.prompts[rid][start:start + chunk]
                 row = jnp.asarray(self._table_row(rid))
-                self.cache = self._insert_prefill(self.cache, cache_one,
-                                                  row, slot)
+                logits, self.cache = self._run(
+                    span, self._prefill_chunk, self.params,
+                    {"tokens": jnp.asarray(toks[None, :])}, self.cache, row,
+                    jnp.int32(start))
             else:
-                self._insert_contiguous(slot, cache_one)
-        done = self.batcher.complete_prefill_chunk(req, chunk)
-        if done:
+                toks = self.prompts[rid][None, :]
+                logits, cache_one = self._run(
+                    span, self._prefill, self.params,
+                    {"tokens": jnp.asarray(toks)}, self.s_max)
+                if self.paged:
+                    # scatter the dense per-request cache into its blocks
+                    row = jnp.asarray(self._table_row(rid))
+                    self.cache = self._run(span, self._insert_prefill,
+                                           self.cache, cache_one, row, slot)
+                else:
+                    self._insert_contiguous(span, slot, cache_one)
+            done = self.batcher.complete_prefill_chunk(req, chunk)
+            if not done:
+                return True
             if self.prefix_cache and self.batcher.chunk_eligible(req):
                 # every full prompt block is now written: publish the chain
                 # so later prompts sharing the prefix adopt instead of
@@ -726,6 +777,8 @@ class ServingEngine:
                 self.alloc.publish_prefix(rid, self._keys.get(rid, []))
             nxt = int(jnp.argmax(logits[0, -1]))
             self.outputs[rid].append(nxt)
+            if req.first_token_at is None:
+                req.first_token_at = self.batcher.now()
             req.generated += 1
             if (self.eos is not None and nxt == self.eos) or \
                     req.generated >= req.max_new_tokens:
@@ -743,10 +796,17 @@ class ServingEngine:
     def step(self) -> int:
         """One engine step: evict, admit+prefill (possibly chunked),
         decode.  Returns the number of active slots stepped."""
-        plan: BatchPlan = self.batcher.plan_step()
-        for req in plan.evicted:
-            self._clear_slot(req)
-            self._release(req.rid)
+        with StepTraceAnnotation("serve.step",
+                                 step_num=self.batcher.metrics["steps"],
+                                 device=self._device_id):
+            return self._step()
+
+    def _step(self) -> int:
+        with TraceAnnotation("serve.plan"):
+            plan: BatchPlan = self.batcher.plan_step()
+            for req in plan.evicted:
+                self._clear_slot(req)
+                self._release(req.rid)
         self._pending_prefill = list(plan.prefill)
         for req in plan.prefill:
             self._pending_prefill.remove(req)
@@ -756,45 +816,60 @@ class ServingEngine:
         # draft/verify and skip plain decode this step
         handled: set = set()
         if self.speculator is not None:
-            handled = self.speculator.round(self)
+            with TraceAnnotation("serve.speculate") as span:
+                seen = _compiled()
+                handled = self.speculator.round(self)
+                self._note_compiles(span, seen)
         # decode every occupied slot at its OWN position (attention_decode
         # takes per-sequence positions — continuous batching mixes depths)
         active = [i for i, r in enumerate(self.slot_req)
                   if r is not None and i not in handled]
-        if self.paged:
-            # the next write position may cross into a new block
-            for i in list(active):
-                req = self.slot_req[i]
-                if req is None:
-                    continue          # preempted by an earlier iteration
-                if not self._ensure_blocks(
-                        req, int(self.slot_pos[i]) % self.cap + 1):
-                    self._preempt_running(req)   # pool starved: recompute
-                elif self.prefix_cache and not self._cow_for_write(req, i):
-                    self._preempt_running(req)   # fork needed, pool starved
-            active = [i for i, r in enumerate(self.slot_req)
-                      if r is not None and i not in handled]
-        if active:
-            pos_vec = jnp.asarray(self.slot_pos, jnp.int32)
-            if self.paged:
+        if self.paged and active:
+            with TraceAnnotation("serve.blocks"):
+                # the next write position may cross into a new block
+                for i in list(active):
+                    req = self.slot_req[i]
+                    if req is None:
+                        continue      # preempted by an earlier iteration
+                    if not self._ensure_blocks(
+                            req, int(self.slot_pos[i]) % self.cap + 1):
+                        self._preempt_running(req)  # pool starved: recompute
+                    elif self.prefix_cache and \
+                            not self._cow_for_write(req, i):
+                        self._preempt_running(req)  # fork, pool starved
+                active = [i for i, r in enumerate(self.slot_req)
+                          if r is not None and i not in handled]
                 # refresh + re-upload the table only when something moved
                 # (slot churn or block alloc/free); steady-state decode
                 # reuses the cached device array
-                if self._table_dirty or \
-                        self._alloc_seen != self.alloc.version:
+                if active and (self._table_dirty or
+                               self._alloc_seen != self.alloc.version):
                     for i in active:
                         self.table[i] = self._table_row(
                             self.slot_req[i].rid)
                     self._table_dev = jnp.asarray(self.table)
                     self._alloc_seen = self.alloc.version
                     self._table_dirty = False
-                logits, self.cache = self._decode(
-                    self.params, self.last_token, self.cache,
-                    self._table_dev, pos_vec)
+        if not active:
+            return len(handled)
+        # live_tokens: the positions the rows attend over, the sum the
+        # attention's bytes and FLOPs are linear in
+        live = np.minimum(self.slot_pos[active] + 1, self.cap)
+        with TraceAnnotation("serve.decode", rows=len(active),
+                             live_tokens=int(live.sum())) as span:
+            pos_vec = jnp.asarray(self.slot_pos, jnp.int32)
+            if self.paged:
+                logits, self.cache = self._run(
+                    span, self._decode, self.params, self.last_token,
+                    self.cache, self._table_dev, pos_vec)
             else:
-                logits, self.cache = self._decode(
-                    self.params, self.last_token, self.cache, pos_vec)
+                logits, self.cache = self._run(
+                    span, self._decode, self.params, self.last_token,
+                    self.cache, pos_vec)
             nxt = jnp.argmax(logits[:, -1], axis=-1)
+        with TraceAnnotation("serve.wait"):
+            nxt.block_until_ready()
+        with TraceAnnotation("serve.commit"):
             for i in active:
                 req = self.slot_req[i]
                 tok = int(nxt[i])
