@@ -297,7 +297,8 @@ class ContinuousBatcher:
                         "deadline_misses": 0, "prefill_chunks": 0,
                         "calls_converted": 0, "preempted": 0,
                         "rejected": 0, "truncated": 0,
-                        "wrapped_oversize": 0, "compiles": 0}
+                        "wrapped_oversize": 0, "compiles": 0,
+                        "decode_host_reads": 0}
         # thieves probe load counters far more often than queues mutate, so
         # the O(queue) scans are cached behind a mutation version stamp
         self._version = 0

@@ -119,7 +119,9 @@ class ServingEngine:
             admission=admission)
         self.slot_req: List[Optional[Request]] = [None] * max_batch
         self.slot_pos = np.zeros(max_batch, np.int64)
-        self.last_token = jnp.zeros((max_batch, 1), jnp.int32)
+        #: each slot's last token, kept on the host (the tokens are read
+        #: back anyway) and uploaded once per decode
+        self.last_token = np.zeros((max_batch, 1), np.int32)
         self.outputs: Dict[int, List[int]] = {}
         self.prompts: Dict[int, np.ndarray] = {}
         #: prefill requests of the CURRENT plan not yet executed — popped
@@ -597,8 +599,7 @@ class ServingEngine:
                 finished = True
                 break
         self.slot_pos[slot] += applied
-        self.last_token = self.last_token.at[slot, 0].set(
-            accepted[applied - 1])
+        self.last_token[slot, 0] = accepted[applied - 1]
         if finished:
             req.state = RequestState.DONE
             req.finished_at = time.monotonic()
@@ -683,7 +684,7 @@ class ServingEngine:
                    pos: int) -> None:
         self.slot_req[slot] = req
         self.slot_pos[slot] = pos
-        self.last_token = self.last_token.at[slot, 0].set(last_tok)
+        self.last_token[slot, 0] = last_tok
         if self.paged:
             self.table[slot] = self._table_row(req.rid)
             self._table_dirty = True
@@ -858,25 +859,31 @@ class ServingEngine:
         with TraceAnnotation("serve.decode", rows=len(active),
                              live_tokens=int(live.sum())) as span:
             pos_vec = jnp.asarray(self.slot_pos, jnp.int32)
+            # a copy: on the CPU ``jnp.asarray`` would alias the buffer the
+            # commit below writes into
+            last = jnp.array(self.last_token)
             if self.paged:
                 logits, self.cache = self._run(
-                    span, self._decode, self.params, self.last_token,
-                    self.cache, self._table_dev, pos_vec)
+                    span, self._decode, self.params, last, self.cache,
+                    self._table_dev, pos_vec)
             else:
                 logits, self.cache = self._run(
-                    span, self._decode, self.params, self.last_token,
-                    self.cache, pos_vec)
+                    span, self._decode, self.params, last, self.cache,
+                    pos_vec)
             nxt = jnp.argmax(logits[:, -1], axis=-1)
         with TraceAnnotation("serve.wait"):
             nxt.block_until_ready()
-        with TraceAnnotation("serve.commit"):
-            for i in active:
-                req = self.slot_req[i]
-                tok = int(nxt[i])
+        with TraceAnnotation("serve.commit", rows=len(active), reads=1):
+            # the step's tokens in one transfer; the rest is host work
+            toks = np.asarray(jax.device_get(nxt))
+            self.batcher.metrics["decode_host_reads"] += 1
+            self.last_token[active, 0] = toks[active]
+            self.slot_pos[active] += 1
+            reqs = [self.slot_req[i] for i in active]
+            self.batcher.complete_decode(reqs)
+            for i, req in zip(active, reqs):
+                tok = int(toks[i])
                 self.outputs[req.rid].append(tok)
-                self.slot_pos[i] += 1
-                self.last_token = self.last_token.at[i, 0].set(tok)
-                self.batcher.complete_decode([req])
                 if (self.eos is not None and tok == self.eos) or \
                         req.generated >= req.max_new_tokens:
                     req.state = RequestState.DONE

@@ -35,14 +35,15 @@ def _serve_spans(path: Path):
     return sorted(out, key=lambda s: (s[0], -s[1]))
 
 
-def _traced_serve(tmp_path, chunk, spec=False, lens=(8, 8, 8)):
+def _traced_serve(tmp_path, chunk, spec=False, lens=(8, 8, 8),
+                  max_batch=2):
     """Serve ``lens`` prompts on a fresh tiny paged engine (fresh model
     functions, so every program compiles once here) under a profiler trace,
     recording what each program call and each executed chunk saw."""
     cfg = scale_down(get_config("qwen2-1.5b"))
     model = build_model(cfg)
     params = model.init(KEY)
-    eng = ServingEngine(model, params, max_batch=2, s_max=64,
+    eng = ServingEngine(model, params, max_batch=max_batch, s_max=64,
                         prefill_chunk=chunk, prefill_token_budget=16,
                         speculator=Speculator(model, params, k=2)
                         if spec else None)
@@ -104,6 +105,20 @@ def test_step_spans_enclose_phases_in_order(tmp_path, chunk):
     # the decode span's rows and live tokens: the slots the call stepped
     assert [(p[3]["rows"], p[3]["live_tokens"])
             for p in phases if p[2] == "serve.decode"] == rec["decode"]
+
+
+def test_commit_reads_the_steps_tokens_back_once(tmp_path):
+    """Every decode step brings its tokens to the host in one transfer,
+    however many rows it decoded."""
+    _, eng, _, _, spans = _traced_serve(tmp_path, 8, lens=(8, 8, 8, 8),
+                                        max_batch=4)
+    decodes = [s[3] for s in spans if s[2] == "serve.decode"]
+    commits = [s[3] for s in spans if s[2] == "serve.commit"]
+    assert max(d["rows"] for d in decodes) >= 3
+    assert [c["rows"] for c in commits] == [d["rows"] for d in decodes]
+    assert all(c["reads"] == 1 for c in commits)
+    assert eng.batcher.metrics["decode_host_reads"] == len(decodes) \
+        < sum(d["rows"] for d in decodes)
 
 
 def test_speculation_round_is_its_own_phase(tmp_path):

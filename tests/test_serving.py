@@ -35,21 +35,27 @@ def test_engine_completes_all_requests():
     assert eng.batcher.metrics["merged_prefills"] >= 1
 
 
-def test_engine_matches_sequential_generation():
+@pytest.mark.parametrize("lens,budgets", [
+    ((6, 11), (3, 3)),
+    # more prompts than slots, unequal budgets: one row finishes in the
+    # middle of a batched step while the other decodes on, and the freed
+    # slot is refilled by a later step's prefill
+    ((6, 11, 9), (2, 5, 4)),
+], ids=["equal", "refill"])
+def test_engine_matches_sequential_generation(lens, budgets):
     """Continuous batching must not change what a request generates."""
     cfg, model, params, eng = _engine(max_batch=2, s_max=32)
     rng = np.random.default_rng(1)
-    prompts = [rng.integers(0, cfg.vocab_size, 6),
-               rng.integers(0, cfg.vocab_size, 11)]
-    reqs = [eng.submit(p, max_new_tokens=3) for p in prompts]
+    prompts = [rng.integers(0, cfg.vocab_size, n) for n in lens]
+    reqs = [eng.submit(p, max_new_tokens=b) for p, b in zip(prompts, budgets)]
     outs = eng.run_until_drained()
 
-    for p, r in zip(prompts, reqs):
+    for p, r, b in zip(prompts, reqs, budgets):
         toks = jnp.asarray(p[None, :])
         logits, cache = model.prefill(params, {"tokens": toks}, 32)
         seq = [int(jnp.argmax(logits[0, -1]))]
         pos = len(p)
-        for _ in range(2):
+        for _ in range(b - 1):
             lg, cache = model.decode_step(
                 params, jnp.asarray([[seq[-1]]], jnp.int32), cache,
                 jnp.int32(pos))
