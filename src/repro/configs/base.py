@@ -28,11 +28,37 @@ class ModelConfig:
     sliding_window: Optional[int] = None
     rope_theta: float = 10_000.0
     tie_embeddings: bool = False
+    # latent attention (MLA, DeepSeek-V2 §2.1); kv_lora_rank > 0 turns it on.
+    # Keys and values are rebuilt from one cached latent of kv_lora_rank
+    # values plus a rotary key of qk_rope_head_dim values shared by all heads
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rotary scaling (factor 0 = plain RoPE); applied to the rotary dims
+    yarn_factor: float = 0.0
+    yarn_original_max_position: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     # MoE
     num_experts: int = 0
     num_experts_per_tok: int = 0
     moe_d_ff: int = 0            # expert hidden size (0 → d_ff)
     moe_layer_period: int = 1    # every n-th layer is MoE (1 = all)
+    #: leading layers with a dense MLP of width d_ff before the MoE layers
+    first_dense_layers: int = 0
+    #: width of the shared-expert SwiGLU every token passes (0 = none)
+    shared_expert_d_ff: int = 0
+    #: the experts this chip holds of the num_experts the router scores
+    #: (0 = all): experts expert_offset .. expert_offset + experts_held - 1.
+    #: The layer computes only their part of the routed result
+    experts_held: int = 0
+    expert_offset: int = 0
+    #: renormalise the top-k router weights to sum to 1 (Mixtral) or keep
+    #: them as softmax probabilities (DeepSeek-V2: norm_topk_prob false)
+    moe_norm_topk: bool = True
     capacity_factor: float = 1.25
     dispatch_policy: str = "priority"   # strategy scheduling | "arrival"
     dispatch_resteal: bool = True       # second-choice restealing
@@ -91,6 +117,20 @@ class ModelConfig:
     @property
     def resolved_moe_d_ff(self) -> int:
         return self.moe_d_ff or self.d_ff
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def mla_latent_width(self) -> int:
+        """Values cached per token and layer: the latent and the rotary
+        key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def resolved_experts_held(self) -> int:
+        return self.experts_held or self.num_experts
 
     @property
     def mamba_d_inner(self) -> int:
@@ -154,6 +194,19 @@ def scale_down(cfg: ModelConfig, *, layers: int = 2, d_model: int = 64,
         kw["num_experts"] = experts or min(cfg.num_experts, 4)
         kw["num_experts_per_tok"] = min(cfg.num_experts_per_tok, 2)
         kw["moe_d_ff"] = d_ff
+        if cfg.experts_held:             # a share stays a share: half
+            kw["experts_held"] = max(1, kw["num_experts"] // 2)
+            kw["expert_offset"] = 0
+        if cfg.shared_expert_d_ff:
+            kw["shared_expert_d_ff"] = d_ff
+    if cfg.first_dense_layers:
+        kw["first_dense_layers"] = 1
+        kw["num_layers"] = max(layers, 2)
+    if cfg.is_mla:
+        hd = d_model // nh
+        kw.update(num_kv_heads=nh, kv_lora_rank=d_model // 2,
+                  qk_nope_head_dim=hd, qk_rope_head_dim=hd // 2,
+                  v_head_dim=hd)
     if cfg.num_encoder_layers:
         kw["num_encoder_layers"] = layers
     if cfg.vision_embed_dim:

@@ -21,7 +21,7 @@ data-dependent control flow.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -57,13 +57,15 @@ def _dispatch_once(e: jax.Array, prio: jax.Array, num_experts: int,
     a = e.shape[0]
     # lexsort: primary key experts ascending, secondary priority descending.
     # Routing decisions are not differentiated (gradients flow through the
-    # combine gates only), so cut the tangent before the sort.
+    # combine gates only), so cut the tangent before the sort.  An id of
+    # ``num_experts`` (an expert another share holds) sorts last and is
+    # never kept.
     order = jnp.lexsort((-jax.lax.stop_gradient(prio), e))
     e_sorted = e[order]
-    seg_start = jnp.searchsorted(e_sorted, jnp.arange(num_experts),
+    seg_start = jnp.searchsorted(e_sorted, jnp.arange(num_experts + 1),
                                  side="left")
     pos_sorted = jnp.arange(a, dtype=jnp.int32) - seg_start[e_sorted].astype(jnp.int32)
-    keep_sorted = pos_sorted < capacity
+    keep_sorted = (pos_sorted < capacity) & (e_sorted < num_experts)
     pos = jnp.zeros(a, jnp.int32).at[order].set(pos_sorted)
     keep = jnp.zeros(a, bool).at[order].set(keep_sorted)
     return pos, keep
@@ -75,7 +77,8 @@ def _dispatch_once(e: jax.Array, prio: jax.Array, num_experts: int,
 def priority_dispatch(expert_idx: jax.Array, gate: jax.Array,
                       full_probs: jax.Array, *, num_experts: int,
                       capacity: int, policy: str = "priority",
-                      resteal: bool = False) -> DispatchPlan:
+                      resteal: bool = False,
+                      held: Optional[jax.Array] = None) -> DispatchPlan:
     """Build the dispatch plan for [T, k] routed assignments.
 
     policy="priority": strategy scheduling — highest router prob survives.
@@ -83,11 +86,21 @@ def priority_dispatch(expert_idx: jax.Array, gate: jax.Array,
                        order), the moral equivalent of LIFO/FIFO.
     resteal=True:      dropped assignments take the token's next-best expert
                        with spare capacity (one extra pass).
+    held:              [T, k] bool — for a layer that holds a share of the
+                       experts: ``expert_idx`` counts within the share, and
+                       an assignment to an expert outside it is another
+                       chip's work: never kept, not counted as dropped.
     """
     t, k = expert_idx.shape
     a = t * k
     e = expert_idx.reshape(a)
     g = gate.reshape(a)
+    mine = jnp.ones(a, bool)
+    if held is not None:
+        if resteal:
+            raise ValueError("restealing needs every expert in the share")
+        mine = held.reshape(a)
+        e = jnp.where(mine, e, num_experts)
     arrival = -jnp.arange(a, dtype=jnp.float32)   # earlier = higher prio
     prio = g if policy == "priority" else arrival
 
@@ -123,7 +136,7 @@ def priority_dispatch(expert_idx: jax.Array, gate: jax.Array,
         (jnp.arange(num_experts)[:, None] == e[None, :]) & keep[None, :],
         axis=1).astype(jnp.int32)
     gate_kept = jnp.where(keep, g, 0.0)
-    dropped_mass = jnp.sum(jnp.where(keep, 0.0, g))
+    dropped_mass = jnp.sum(jnp.where(keep | ~mine, 0.0, g))
     return DispatchPlan(slot_src=slot_src,
                         kept=keep.reshape(t, k),
                         expert=e.reshape(t, k).astype(jnp.int32),

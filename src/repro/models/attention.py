@@ -1,5 +1,8 @@
 """Grouped-query attention with RoPE, optional sliding window, qk-norm and
 QKV bias; full-sequence (training/prefill) and single-token (decode) paths.
+Latent attention (MLA, ``cfg.is_mla``) takes the same entry points: its
+cache holds one latent per token instead of per-head K and V (see the MLA
+section below).
 
 The decode path supports a sequence-sharded KV cache (long-context): the
 attention below is written as plain einsums + softmax so XLA's SPMD
@@ -15,12 +18,13 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
-from .layers import apply_rope, init_linear, init_rms_norm, linear, rms_norm
+from .layers import (apply_rope, apply_rope_inv, init_linear, init_rms_norm,
+                     linear, rms_norm, yarn_inv_freq, yarn_mscale)
 
 __all__ = ["init_attention", "attention_fwd", "attention_decode", "KVCache",
            "PagedKVCache", "attention_decode_paged",
            "attention_prefill_chunk_paged", "attention_verify_paged",
-           "init_paged_kv_cache"]
+           "init_paged_kv_cache", "LatentKVCache", "LatentPagedCache"]
 
 
 class KVCache(NamedTuple):
@@ -37,7 +41,22 @@ class PagedKVCache(NamedTuple):
     v: jax.Array   # [num_blocks, block_size, kvH, hd]
 
 
+class LatentKVCache(NamedTuple):
+    """MLA's cache: per token the normalised latent (``kv_lora_rank``) and
+    the rotated rotary key (``qk_rope_head_dim``), side by side."""
+    c: jax.Array   # [B, S_max, kv_lora_rank + qk_rope_head_dim]
+
+
+class LatentPagedCache(NamedTuple):
+    """MLA's paged pool: blocks of latents, addressed like
+    :class:`PagedKVCache`; the values are the latent's first
+    ``kv_lora_rank`` entries, so there is no separate V leaf."""
+    c: jax.Array   # [num_blocks, block_size, kv_lora_rank + qk_rope_head_dim]
+
+
 def init_attention(key, cfg: ModelConfig, dtype=jnp.bfloat16) -> dict:
+    if cfg.is_mla:
+        return _init_mla(key, cfg, dtype)
     hd = cfg.resolved_head_dim
     k1, k2, k3, k4 = jax.random.split(key, 4)
     p = {
@@ -80,7 +99,7 @@ def _sdpa(q, k, v, mask, scale):
     logits = jnp.where(mask[:, None, None, :, :], logits, -jnp.inf)
     w = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgst,btkd->bskgd", w, v)
-    return out.reshape(b, s, h, hd)
+    return out.reshape(b, s, h, v.shape[-1])
 
 
 #: sequences at least this long take the chunked online-softmax path
@@ -165,7 +184,10 @@ def attention_fwd(p: dict, x: jax.Array, cfg: ModelConfig,
                   return_kv: bool = False):
     """Full-sequence attention.  ``kv`` overrides keys/values for
     cross-attention (tuple of [B,T,kvH,hd]).  With ``return_kv`` the
-    projected k/v are also returned (prefill fills the cache from them)."""
+    projected k/v are also returned as a :class:`KVCache` of the sequence
+    (prefill fills the cache from them)."""
+    if cfg.is_mla:
+        return _mla_fwd(p, x, cfg, positions, return_kv)
     b, s, _ = x.shape
     if positions is None:
         positions = jnp.arange(s)[None, :]
@@ -191,7 +213,7 @@ def attention_fwd(p: dict, x: jax.Array, cfg: ModelConfig,
         out = _sdpa(q, k, v, mask, scale)
     y = linear(p["wo"], out.reshape(b, s, -1))
     if return_kv:
-        return y, (k, v)
+        return y, KVCache(k, v)
     return y
 
 
@@ -231,6 +253,8 @@ def attention_decode(p: dict, x: jax.Array, cache: KVCache, pos: jax.Array,
     (per-sequence positions support continuous batching, where slots are at
     different depths); cache holds S_max past positions (ring-buffered for
     sliding window)."""
+    if cfg.is_mla:
+        return _mla_decode(p, x, cache, pos, cfg)
     b = x.shape[0]
     s_max = cache.k.shape[1]
     pos_vec = jnp.broadcast_to(jnp.asarray(pos).reshape(-1), (b,))
@@ -259,6 +283,8 @@ def attention_decode_paged(p: dict, x: jax.Array, cache: PagedKVCache,
     :func:`attention_decode` over a contiguous cache of capacity
     ``cap = max_blocks * block_size``: the gathered logical view has the
     same width, mask and values, so fp32 decode is bit-identical."""
+    if cfg.is_mla:
+        return _mla_decode_paged(p, x, cache, table, pos, cfg)
     b = x.shape[0]
     bs = cache.k.shape[1]
     cap = table.shape[1] * bs
@@ -296,6 +322,8 @@ def attention_verify_paged(p: dict, x: jax.Array, cache: PagedKVCache,
     routed to an all-sink table row, whose contents are garbage by design
     and never read unmasked.  Always the masked XLA path, like chunked
     prefill (the flash kernel's ``q_offset`` is static per shape)."""
+    if cfg.is_mla:
+        raise NotImplementedError("speculative verify has no MLA form")
     b, c, _ = x.shape
     bs = cache.k.shape[1]
     cap = table.shape[1] * bs
@@ -333,6 +361,8 @@ def attention_prefill_chunk_paged(p: dict, x: jax.Array, cache: PagedKVCache,
     falls back to whole-prompt prefill otherwise).  Always uses the masked
     XLA path: the flash kernel's ``q_offset`` is static, and recompiling per
     chunk boundary would cost more than the chunk."""
+    if cfg.is_mla:
+        return _mla_prefill_chunk_paged(p, x, cache, table_row, start, cfg)
     b, c, _ = x.shape
     bs = cache.k.shape[1]
     cap = table_row.shape[0] * bs
@@ -356,7 +386,10 @@ def attention_prefill_chunk_paged(p: dict, x: jax.Array, cache: PagedKVCache,
 
 
 def init_kv_cache(cfg: ModelConfig, batch: int, s_max: int,
-                  dtype=jnp.bfloat16) -> KVCache:
+                  dtype=jnp.bfloat16):
+    if cfg.is_mla:
+        return LatentKVCache(jnp.zeros((batch, s_max, cfg.mla_latent_width),
+                                       dtype))
     hd = cfg.resolved_head_dim
     if cfg.sliding_window is not None:
         s_max = min(s_max, cfg.sliding_window)
@@ -365,6 +398,171 @@ def init_kv_cache(cfg: ModelConfig, batch: int, s_max: int,
 
 
 def init_paged_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
-                        dtype=jnp.bfloat16) -> PagedKVCache:
+                        dtype=jnp.bfloat16):
+    if cfg.is_mla:
+        return LatentPagedCache(jnp.zeros(
+            (num_blocks, block_size, cfg.mla_latent_width), dtype))
     shape = (num_blocks, block_size, cfg.num_kv_heads, cfg.resolved_head_dim)
     return PagedKVCache(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+
+
+# --------------------------------------------------------------------------
+# Latent attention (MLA, DeepSeek-V2 §2.1)
+#
+# Per token, x projects to one latent c = RMSNorm(x W_dkv) of kv_lora_rank
+# values and one rotary key k_R = RoPE(x W_kr) shared by every head; the
+# cache keeps [c || k_R].  Head h's key is [c W_uk[h] || k_R] and its value
+# c W_uv[h] (W_uk, W_uv: the two halves of kv_b_proj).  Prefill expands the
+# latents into keys and values (the paper's equations); decode absorbs
+# W_uk into the query instead, so one query row of kv_lora_rank +
+# qk_rope_head_dim values scores the cached latents directly and the
+# softmax-weighted latent goes through W_uv afterwards: the cache is read as
+# one shared head for all query heads.  Every MLA op runs under
+# ``jax.named_scope("mla")``.
+# --------------------------------------------------------------------------
+
+def _init_mla(key, cfg: ModelConfig, dtype) -> dict:
+    h, r = cfg.num_heads, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    k1, k2, k3, k4 = jax.random.split(key, 4)
+    return {"wq": init_linear(k1, cfg.d_model, h * (nope + rope), dtype=dtype),
+            "wkv_a": init_linear(k2, cfg.d_model, r + rope, dtype=dtype),
+            "kv_norm": init_rms_norm(r, dtype),
+            "wkv_b": init_linear(k3, r, h * (nope + vd), dtype=dtype),
+            "wo": init_linear(k4, h * vd, cfg.d_model, dtype=dtype)}
+
+
+def _mla_softmax_scale(cfg: ModelConfig) -> float:
+    """``(nope + rope) ** -0.5``, times YaRN's ``mscale_all_dim``
+    temperature squared."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.yarn_factor > 1 and cfg.yarn_mscale_all_dim:
+        m = yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)
+        scale *= m * m
+    return scale
+
+
+def _mla_rope(x: jax.Array, positions: jax.Array, cfg: ModelConfig):
+    inv = yarn_inv_freq(cfg.qk_rope_head_dim, cfg.rope_theta,
+                        cfg.yarn_factor, cfg.yarn_original_max_position,
+                        cfg.yarn_beta_fast, cfg.yarn_beta_slow)
+    scale = 1.0
+    if cfg.yarn_factor > 1:
+        scale = (yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+                 / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+    return apply_rope_inv(x, positions, inv.astype("float32"), scale)
+
+
+def _mla_project(p: dict, x: jax.Array, cfg: ModelConfig, positions):
+    """x: [B, S, D] → (q_nope [B,S,H,nope], rotated q_rope [B,S,H,rope],
+    latents [B, S, r + rope])."""
+    b, s, _ = x.shape
+    r, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    q = linear(p["wq"], x).reshape(b, s, cfg.num_heads, -1)
+    q_rope = _mla_rope(q[..., nope:], positions, cfg)
+    ckv = linear(p["wkv_a"], x)
+    c = rms_norm(p["kv_norm"], ckv[..., :r], cfg.norm_eps)
+    k_rope = _mla_rope(ckv[..., None, r:], positions, cfg)[..., 0, :]
+    return q[..., :nope], q_rope, jnp.concatenate([c, k_rope], -1)
+
+
+def _mla_expand(p: dict, lat: jax.Array, cfg: ModelConfig):
+    """Latents [B, T, r + rope] → keys [B,T,H,nope+rope], values [B,T,H,v]."""
+    b, t, _ = lat.shape
+    h, r, nope = cfg.num_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    kv = linear(p["wkv_b"], lat[..., :r]).reshape(b, t, h, -1)
+    k_rope = jnp.broadcast_to(lat[..., None, r:],
+                              (b, t, h, cfg.qk_rope_head_dim))
+    return jnp.concatenate([kv[..., :nope], k_rope], -1), kv[..., nope:]
+
+
+def _mla_fwd(p: dict, x: jax.Array, cfg: ModelConfig, positions,
+             return_kv: bool):
+    """Full-sequence MLA in the expanded form, causal."""
+    with jax.named_scope("mla"):
+        b, s, _ = x.shape
+        if positions is None:
+            positions = jnp.arange(s)[None, :]
+        q_nope, q_rope, lat = _mla_project(p, x, cfg, positions)
+        k, v = _mla_expand(p, lat, cfg)
+        out = _sdpa(jnp.concatenate([q_nope, q_rope], -1), k, v,
+                    causal_mask(s)[None], _mla_softmax_scale(cfg))
+        y = linear(p["wo"], out.reshape(b, s, -1))
+    if return_kv:
+        return y, LatentKVCache(lat)
+    return y
+
+
+def _mla_attend_latents(p: dict, q_nope, q_rope, lat: jax.Array,
+                        pos_vec: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """Absorbed decode: one query row per sequence ([B, 1, H, ...]) over
+    the logical latent view ``lat`` [B, cap, r + rope] at per-sequence
+    positions; returns the attention output [B, 1, D]."""
+    b, cap, _ = lat.shape
+    h, r, nope = cfg.num_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    w = p["wkv_b"]["w"].reshape(r, h, -1)
+    q = jnp.concatenate(
+        [jnp.einsum("bshn,rhn->bshr", q_nope, w[..., :nope]), q_rope], -1)
+    scale = _mla_softmax_scale(cfg)
+    if cfg.use_flash:
+        from ..kernels.mla_decode.ops import mla_decode
+        kv_valid = jnp.minimum(pos_vec + 1, cap).astype(jnp.int32)
+        o = mla_decode(q[:, 0], lat, kv_valid, rank=r, scale=scale)[:, None]
+    else:
+        valid = jnp.arange(cap)[None, :] <= pos_vec[:, None]
+        o = _sdpa(q, lat[:, :, None], lat[:, :, None, :r], valid[:, None, :],
+                  scale)
+    out = jnp.einsum("bshr,rhv->bshv", o.astype(w.dtype), w[..., nope:])
+    return linear(p["wo"], out.reshape(b, 1, -1))
+
+
+def _mla_decode(p: dict, x: jax.Array, cache: LatentKVCache, pos,
+                cfg: ModelConfig):
+    with jax.named_scope("mla"):
+        b = x.shape[0]
+        s_max = cache.c.shape[1]
+        pos_vec = jnp.broadcast_to(jnp.asarray(pos).reshape(-1), (b,))
+        q_nope, q_rope, lat = _mla_project(p, x, cfg, pos_vec[:, None])
+        c = cache.c.at[jnp.arange(b), pos_vec % s_max].set(
+            lat[:, 0].astype(cache.c.dtype))
+        y = _mla_attend_latents(p, q_nope, q_rope, c, pos_vec, cfg)
+    return y, LatentKVCache(c)
+
+
+def _mla_decode_paged(p: dict, x: jax.Array, cache: LatentPagedCache,
+                      table: jax.Array, pos, cfg: ModelConfig):
+    with jax.named_scope("mla"):
+        b = x.shape[0]
+        bs = cache.c.shape[1]
+        cap = table.shape[1] * bs
+        pos_vec = jnp.broadcast_to(jnp.asarray(pos).reshape(-1), (b,))
+        q_nope, q_rope, lat = _mla_project(p, x, cfg, pos_vec[:, None])
+        slot = pos_vec % cap
+        blk = jnp.take_along_axis(table, (slot // bs)[:, None], axis=1)[:, 0]
+        pool = cache.c.at[blk, slot % bs].set(lat[:, 0].astype(cache.c.dtype))
+        view = pool[table].reshape(b, cap, -1)
+        y = _mla_attend_latents(p, q_nope, q_rope, view, pos_vec, cfg)
+    return y, LatentPagedCache(pool)
+
+
+def _mla_prefill_chunk_paged(p: dict, x: jax.Array, cache: LatentPagedCache,
+                             table_row: jax.Array, start, cfg: ModelConfig):
+    """One prompt chunk: its latents go into the pool, then its queries
+    attend, in the expanded form, over every latent of the request's view
+    (earlier chunks' read back from the pool) under a bottom-right causal
+    mask.  Same contract as :func:`attention_prefill_chunk_paged`."""
+    with jax.named_scope("mla"):
+        b, c, _ = x.shape
+        bs = cache.c.shape[1]
+        cap = table_row.shape[0] * bs
+        rows = jnp.asarray(start, jnp.int32) + jnp.arange(c, dtype=jnp.int32)
+        q_nope, q_rope, lat = _mla_project(p, x, cfg, rows[None, :])
+        pool = cache.c.at[table_row[rows // bs], rows % bs].set(
+            lat[0].astype(cache.c.dtype))
+        k, v = _mla_expand(p, pool[table_row].reshape(1, cap, -1), cfg)
+        valid = jnp.arange(cap, dtype=jnp.int32)[None, None, :] \
+            <= rows[None, :, None]
+        out = _sdpa(jnp.concatenate([q_nope, q_rope], -1), k, v, valid,
+                    _mla_softmax_scale(cfg))
+        y = linear(p["wo"], out.reshape(b, c, -1))
+    return y, LatentPagedCache(pool)

@@ -6,15 +6,18 @@ normalization statistics in fp32.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..configs.base import ModelConfig
 
 __all__ = ["dtype_of", "init_linear", "linear", "init_rms_norm", "rms_norm",
            "init_embedding", "embed", "rope_freqs", "apply_rope",
+           "yarn_inv_freq", "yarn_mscale", "apply_rope_inv",
            "init_mlp", "mlp", "init_group_norm", "group_norm"]
 
 
@@ -104,6 +107,53 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     ang = positions[..., None].astype(jnp.float32) * inv   # [..., S, hd/2]
     cos = jnp.cos(ang)[..., None, :]                       # [..., S, 1, hd/2]
     sin = jnp.sin(ang)[..., None, :]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return out.astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature: ``0.1 * mscale * ln(factor) + 1``."""
+    if factor <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float,
+                  original_max_position: int, beta_fast: float,
+                  beta_slow: float) -> np.ndarray:
+    """YaRN inverse frequencies of a ``dim``-wide rotary embedding (the
+    DeepSeek-V2 form): dimensions that turn more than ``beta_fast`` times
+    over the original context keep their frequency, those that turn fewer
+    than ``beta_slow`` times are slowed by ``factor``, and a linear ramp
+    blends the ones between.  ``factor <= 1`` is plain RoPE."""
+    base = theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    extra = 1.0 / base
+    if factor <= 1:
+        return extra
+
+    def turns_dim(turns):
+        return dim * math.log(original_max_position / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(turns_dim(beta_fast)), 0)
+    high = min(math.ceil(turns_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return extra / factor * (1.0 - keep) + extra * keep
+
+
+def apply_rope_inv(x: jax.Array, positions: jax.Array, inv,
+                   scale: float = 1.0) -> jax.Array:
+    """Rotate-half RoPE with given inverse frequencies ``inv`` [hd/2] and
+    cos/sin scaled by ``scale``.  x: [..., S, H, hd]; positions
+    broadcastable to [..., S]."""
+    inv = jnp.asarray(inv, jnp.float32)
+    ang = positions[..., None].astype(jnp.float32) * inv
+    cos = (jnp.cos(ang) * scale)[..., None, :]
+    sin = (jnp.sin(ang) * scale)[..., None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
     return out.astype(x.dtype)

@@ -98,8 +98,10 @@ def _build(cfg: ModelConfig) -> Model:
             prefill_chunk_paged=lambda p, b, cache, row, start:
                 transformer.lm_prefill_chunk_paged(p, b, cache, row, start,
                                                    cfg),
-            verify_paged=lambda p, toks, cache, table, pos:
-                transformer.lm_verify_paged(p, toks, cache, table, pos, cfg),
+            # speculative verify has no latent-attention (MLA) form
+            verify_paged=None if cfg.is_mla else
+            (lambda p, toks, cache, table, pos:
+                transformer.lm_verify_paged(p, toks, cache, table, pos, cfg)),
         )
     if fam == "ssm":
         return Model(

@@ -5,6 +5,11 @@ layer axis (``vmap`` over init) and the forward is a ``lax.scan`` over
 layers — HLO size stays O(1) in depth, which keeps 88-layer × 512-device
 compiles tractable.  ``jax.checkpoint`` on the block body gives per-layer
 rematerialization.
+
+A model with ``cfg.first_dense_layers`` leading dense layers (DeepSeek-V2)
+is two such stacks, one scan each: ``dense_blocks`` with a dense MLP, then
+``blocks``.  Its caches are then a tuple with one layer-stacked cache per
+stack, so no call slices or joins a whole cache.
 """
 from __future__ import annotations
 
@@ -14,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
-from .attention import (KVCache, PagedKVCache, attention_decode,
+from .attention import (KVCache, LatentPagedCache, attention_decode,
                         attention_decode_paged, attention_fwd,
                         attention_prefill_chunk_paged, attention_verify_paged,
                         init_attention, init_kv_cache, init_paged_kv_cache)
@@ -59,50 +64,87 @@ def _pin(x: jax.Array, cfg: ModelConfig) -> jax.Array:
         return x
 
 
-def _init_block(key, cfg: ModelConfig) -> dict:
+def _stacks(cfg: ModelConfig) -> list:
+    """(params key, number of layers, MoE?) of each stack, in order."""
+    lead = cfg.first_dense_layers
+    main = ("blocks", cfg.num_layers - lead, _is_moe(cfg))
+    return ([("dense_blocks", lead, False)] if lead else []) + [main]
+
+
+def _split(cache, cfg: ModelConfig) -> list:
+    """One cache per stack (see the module docstring)."""
+    return list(cache) if cfg.first_dense_layers else [cache]
+
+
+def _join(caches: list, cfg: ModelConfig):
+    return tuple(caches) if cfg.first_dense_layers else caches[0]
+
+
+def _stacked(cfg: ModelConfig, one) -> list:
+    """``one`` layer's cache stacked to each stack's depth."""
+    return [jax.tree.map(
+        lambda a, n=n: jnp.broadcast_to(a[None], (n,) + a.shape).copy(), one)
+        for _, n, _ in _stacks(cfg)]
+
+
+def _scan_stacks(params: dict, x: jax.Array, cache, cfg: ModelConfig,
+                 layer):
+    """Scan each stack's layers over ``x`` and the stack's cache:
+    ``layer(p, h, cache_l, moe) -> (h, new cache_l)``.  Returns the last
+    hidden state and the new cache."""
+    new = []
+    for (name, _, moe), c in zip(_stacks(cfg), _split(cache, cfg)):
+        def body(h, pc, moe=moe):
+            return layer(pc[0], h, pc[1], moe)
+
+        x, nc = jax.lax.scan(body, x, (params[name], c),
+                             unroll=cfg.unroll_scans)
+        new.append(nc)
+    return x, _join(new, cfg)
+
+
+def _init_block(key, cfg: ModelConfig, moe: bool) -> dict:
     dt = dtype_of(cfg)
     k1, k2 = jax.random.split(key)
     p = {"ln1": init_rms_norm(cfg.d_model, dt),
          "attn": init_attention(k1, cfg, dt),
          "ln2": init_rms_norm(cfg.d_model, dt)}
-    if _is_moe(cfg):
+    if moe:
         p["moe"] = init_moe(k2, cfg, dt)
     else:
         p["mlp"] = init_mlp(k2, cfg.d_model, cfg.d_ff, dt)
     return p
 
 
+def _ffn(p: dict, z: jax.Array, cfg: ModelConfig, moe: bool):
+    """The block's MLP (or MoE) and its routing stats."""
+    if moe:
+        # the same kernel selection on every path: decode must not drift
+        return moe_fwd(p["moe"], z, cfg, use_kernel=cfg.use_flash)
+    return mlp(p["mlp"], z), MoEStats(jnp.zeros((1,), jnp.int32),
+                                      jnp.float32(0), jnp.float32(0))
+
+
 def _block_fwd(p: dict, x: jax.Array, cfg: ModelConfig, positions, mask,
-               return_kv: bool = False):
+               moe: bool, return_kv: bool = False):
     attn_out = attention_fwd(p["attn"], rms_norm(p["ln1"], x, cfg.norm_eps),
                              cfg, positions, mask, use_flash=cfg.use_flash,
                              return_kv=return_kv)
     if return_kv:
         attn_out, kv = attn_out
     h = x + attn_out
-    z = rms_norm(p["ln2"], h, cfg.norm_eps)
-    if _is_moe(cfg):
-        y, stats = moe_fwd(p["moe"], z, cfg, use_kernel=cfg.use_flash)
-    else:
-        y = mlp(p["mlp"], z)
-        stats = MoEStats(jnp.zeros((1,), jnp.int32), jnp.float32(0),
-                         jnp.float32(0))
+    y, stats = _ffn(p, rms_norm(p["ln2"], h, cfg.norm_eps), cfg, moe)
     out = _pin(h + y, cfg)
     if return_kv:
         return out, (stats, kv)
     return out, stats
 
 
-def _block_decode(p: dict, x: jax.Array, cache: KVCache, pos, cfg):
+def _block_decode(p: dict, x: jax.Array, cache: KVCache, pos, cfg, moe):
     y_attn, new_cache = attention_decode(
         p["attn"], rms_norm(p["ln1"], x, cfg.norm_eps), cache, pos, cfg)
     h = x + y_attn
-    z = rms_norm(p["ln2"], h, cfg.norm_eps)
-    if _is_moe(cfg):
-        # same kernel selection as the forward path: decode must not drift
-        y, _ = moe_fwd(p["moe"], z, cfg, use_kernel=cfg.use_flash)
-    else:
-        y = mlp(p["mlp"], z)
+    y, _ = _ffn(p, rms_norm(p["ln2"], h, cfg.norm_eps), cfg, moe)
     return h + y, new_cache
 
 
@@ -112,9 +154,13 @@ def init_lm(key, cfg: ModelConfig) -> dict:
     layer_keys = jax.random.split(kl, cfg.num_layers)
     params = {
         "embed": init_embedding(ke, cfg.vocab_size, cfg.d_model, dt),
-        "blocks": jax.vmap(lambda k: _init_block(k, cfg))(layer_keys),
         "ln_f": init_rms_norm(cfg.d_model, dt),
     }
+    first = 0
+    for name, n, moe in _stacks(cfg):
+        params[name] = jax.vmap(lambda k, moe=moe: _init_block(k, cfg, moe))(
+            layer_keys[first:first + n])
+        first += n
     if not cfg.tie_embeddings:
         params["lm_head"] = init_linear(kh, cfg.d_model, cfg.vocab_size,
                                         dtype=dt)
@@ -151,14 +197,16 @@ def lm_forward(params: dict, batch: dict, cfg: ModelConfig) -> LMOutputs:
     x = _pin(_embed_inputs(params, batch, cfg), cfg)
     s = x.shape[1]
     positions = jnp.arange(s)[None, :]
+    for name, _, moe in _stacks(cfg):
+        def body(h, pl, moe=moe):
+            y, st = _block_fwd(pl, h, cfg, positions, None, moe)
+            return y, st
 
-    def body(h, pl):
-        y, stats = _block_fwd(pl, h, cfg, positions, None)
-        return y, stats
-
-    body_fn = jax.checkpoint(body) if cfg.remat else body
-    x, stats = jax.lax.scan(body_fn, x, params["blocks"],
-                            unroll=cfg.unroll_scans)
+        body_fn = jax.checkpoint(body) if cfg.remat else body
+        x, st = jax.lax.scan(body_fn, x, params[name],
+                             unroll=cfg.unroll_scans)
+        if moe:
+            stats = st
     x = rms_norm(params["ln_f"], x, cfg.norm_eps)
     logits = _unembed(params, x, cfg)
     if _is_moe(cfg):
@@ -167,12 +215,9 @@ def lm_forward(params: dict, batch: dict, cfg: ModelConfig) -> LMOutputs:
     return LMOutputs(logits)
 
 
-def init_lm_cache(cfg: ModelConfig, batch: int, s_max: int) -> KVCache:
+def init_lm_cache(cfg: ModelConfig, batch: int, s_max: int):
     one = init_kv_cache(cfg, batch, s_max, dtype_of(cfg))
-    def stack(a):
-        return jnp.broadcast_to(a[None],
-                                (cfg.num_layers,) + a.shape).copy()
-    return KVCache(stack(one.k), stack(one.v))
+    return _join(_stacked(cfg, one), cfg)
 
 
 def lm_prefill(params: dict, batch: dict, cfg: ModelConfig,
@@ -182,44 +227,44 @@ def lm_prefill(params: dict, batch: dict, cfg: ModelConfig,
     b, s, _ = x.shape
     s_max = s_max or s
     positions = jnp.arange(s)[None, :]
+    seqs = []
+    for name, _, moe in _stacks(cfg):
+        def body(h, pl, moe=moe):
+            y, (_, kv) = _block_fwd(pl, h, cfg, positions, None, moe,
+                                    return_kv=True)
+            return y, kv
 
-    def body(h, pl):
-        y, (_, kv) = _block_fwd(pl, h, cfg, positions, None, return_kv=True)
-        return y, kv
-
-    body_fn = jax.checkpoint(body) if cfg.remat else body
-    x, (ks, vs) = jax.lax.scan(body_fn, x, params["blocks"],
-                               unroll=cfg.unroll_scans)
+        body_fn = jax.checkpoint(body) if cfg.remat else body
+        x, kv = jax.lax.scan(body_fn, x, params[name],
+                             unroll=cfg.unroll_scans)
+        seqs.append(kv)
     x = rms_norm(params["ln_f"], x, cfg.norm_eps)
     logits = _unembed(params, x[:, -1:], cfg)
-    # Place the prompt K/V tail into a cache of capacity s_max; ring-align
-    # so that position p sits at slot p % s_max (what decode expects).
-    cache = init_lm_cache(cfg, b, s_max)
-    cap = cache.k.shape[2]
-    w = min(s, cap)
-    tail_k, tail_v = ks[:, :, s - w:s], vs[:, :, s - w:s]
-    if w == cap and s % cap:
-        tail_k = jnp.roll(tail_k, s % cap, axis=2)
-        tail_v = jnp.roll(tail_v, s % cap, axis=2)
-    cache = KVCache(
-        jax.lax.dynamic_update_slice_in_dim(cache.k, tail_k, 0, 2),
-        jax.lax.dynamic_update_slice_in_dim(cache.v, tail_v, 0, 2))
+
+    # Place the prompt's cache tail into a cache of capacity s_max;
+    # ring-align so that position p sits at slot p % s_max (what decode
+    # expects).
+    def place(full, seq):
+        cap = full.shape[2]
+        w = min(s, cap)
+        tail = seq[:, :, s - w:s]
+        if w == cap and s % cap:
+            tail = jnp.roll(tail, s % cap, axis=2)
+        return jax.lax.dynamic_update_slice_in_dim(full, tail, 0, 2)
+
+    cache = jax.tree.map(place, init_lm_cache(cfg, b, s_max),
+                         _join(seqs, cfg))
     return logits, cache
 
 
-def lm_decode_step(params: dict, token: jax.Array, cache: KVCache,
+def lm_decode_step(params: dict, token: jax.Array, cache,
                    pos: jax.Array, cfg: ModelConfig):
     """token: [B, 1] int32; pos: [] position index.  Returns
     (logits [B,1,V], new cache)."""
     x = embed(params["embed"], token, cfg.onehot_embed)
-
-    def body(h, layer):
-        pl, cache_l = layer
-        y, new_c = _block_decode(pl, h, cache_l, pos, cfg)
-        return y, new_c
-
-    x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache),
-                                unroll=cfg.unroll_scans)
+    x, new_cache = _scan_stacks(
+        params, x, cache, cfg,
+        lambda pl, h, cl, moe: _block_decode(pl, h, cl, pos, cfg, moe))
     x = rms_norm(params["ln_f"], x, cfg.norm_eps)
     return _unembed(params, x, cfg), new_cache
 
@@ -228,52 +273,42 @@ def lm_decode_step(params: dict, token: jax.Array, cache: KVCache,
 # Paged KV: decode + chunked prefill through per-request block tables
 # --------------------------------------------------------------------------
 
-def init_lm_paged_cache(cfg: ModelConfig, num_blocks: int,
-                        block_size: int) -> PagedKVCache:
-    """Layer-stacked physical block pool [L, num_blocks, bs, kvH, hd]; the
+def init_lm_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int):
+    """Layer-stacked physical block pool [L, num_blocks, bs, ...]; the
     block table (host-side, ``serving.paged_kv``) is shared across layers —
     block id ``b`` names row ``b`` of every layer's pool."""
+    if cfg.is_mla:
+        # zeros of the stacked shape: no one-layer copy to broadcast
+        shape = (num_blocks, block_size, cfg.mla_latent_width)
+        return _join([LatentPagedCache(jnp.zeros((n,) + shape, dtype_of(cfg)))
+                      for _, n, _ in _stacks(cfg)], cfg)
     one = init_paged_kv_cache(cfg, num_blocks, block_size, dtype_of(cfg))
-    def stack(a):
-        return jnp.broadcast_to(a[None],
-                                (cfg.num_layers,) + a.shape).copy()
-    return PagedKVCache(stack(one.k), stack(one.v))
+    return _join(_stacked(cfg, one), cfg)
 
 
-def _block_decode_paged(p: dict, x: jax.Array, cache: PagedKVCache, table,
-                        pos, cfg):
+def _block_decode_paged(p: dict, x: jax.Array, cache, table, pos, cfg, moe):
     y_attn, new_cache = attention_decode_paged(
         p["attn"], rms_norm(p["ln1"], x, cfg.norm_eps), cache, table, pos,
         cfg)
     h = x + y_attn
-    z = rms_norm(p["ln2"], h, cfg.norm_eps)
-    if _is_moe(cfg):
-        y, _ = moe_fwd(p["moe"], z, cfg, use_kernel=cfg.use_flash)
-    else:
-        y = mlp(p["mlp"], z)
+    y, _ = _ffn(p, rms_norm(p["ln2"], h, cfg.norm_eps), cfg, moe)
     return h + y, new_cache
 
 
-def lm_decode_step_paged(params: dict, token: jax.Array, cache: PagedKVCache,
+def lm_decode_step_paged(params: dict, token: jax.Array, cache,
                          table: jax.Array, pos: jax.Array, cfg: ModelConfig):
     """Paged decode: K/V read through ``table`` [B, max_blocks] instead of a
     dense per-slot buffer.  Bit-identical (fp32) to :func:`lm_decode_step`
     over a contiguous cache of the same logical capacity."""
     x = embed(params["embed"], token, cfg.onehot_embed)
-
-    def body(h, layer):
-        pl, ck, cv = layer
-        y, new_c = _block_decode_paged(pl, h, PagedKVCache(ck, cv), table,
-                                       pos, cfg)
-        return y, new_c
-
-    x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache.k, cache.v),
-                                unroll=cfg.unroll_scans)
+    x, new_cache = _scan_stacks(
+        params, x, cache, cfg, lambda pl, h, cl, moe: _block_decode_paged(
+            pl, h, cl, table, pos, cfg, moe))
     x = rms_norm(params["ln_f"], x, cfg.norm_eps)
-    return _unembed(params, x, cfg), PagedKVCache(new_cache.k, new_cache.v)
+    return _unembed(params, x, cfg), new_cache
 
 
-def lm_prefill_chunk_paged(params: dict, batch: dict, cache: PagedKVCache,
+def lm_prefill_chunk_paged(params: dict, batch: dict, cache,
                            table_row: jax.Array, start: jax.Array,
                            cfg: ModelConfig):
     """Run one chunk of a single request's prompt (tokens [1, c]) against
@@ -282,27 +317,21 @@ def lm_prefill_chunk_paged(params: dict, batch: dict, cache: PagedKVCache,
     on the final chunk (they seed the first generated token)."""
     x = _embed_inputs(params, batch, cfg)
 
-    def body(h, layer):
-        pl, ck, cv = layer
+    def layer(pl, h, cl, moe):
         z = rms_norm(pl["ln1"], h, cfg.norm_eps)
         attn, new_c = attention_prefill_chunk_paged(
-            pl["attn"], z, PagedKVCache(ck, cv), table_row, start, cfg)
+            pl["attn"], z, cl, table_row, start, cfg)
         hh = h + attn
-        zz = rms_norm(pl["ln2"], hh, cfg.norm_eps)
-        if _is_moe(cfg):
-            y, _ = moe_fwd(pl["moe"], zz, cfg, use_kernel=cfg.use_flash)
-        else:
-            y = mlp(pl["mlp"], zz)
+        y, _ = _ffn(pl, rms_norm(pl["ln2"], hh, cfg.norm_eps), cfg, moe)
         return hh + y, new_c
 
-    x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache.k, cache.v),
-                                unroll=cfg.unroll_scans)
+    x, new_cache = _scan_stacks(params, x, cache, cfg, layer)
     x = rms_norm(params["ln_f"], x, cfg.norm_eps)
     logits = _unembed(params, x[:, -1:], cfg)
-    return logits, PagedKVCache(new_cache.k, new_cache.v)
+    return logits, new_cache
 
 
-def lm_verify_paged(params: dict, tokens: jax.Array, cache: PagedKVCache,
+def lm_verify_paged(params: dict, tokens: jax.Array, cache,
                     table: jax.Array, pos: jax.Array, cfg: ModelConfig):
     """Speculative verification step: run ``c`` tokens per sequence
     (``tokens`` [B, c] — the last accepted token followed by the draft's
@@ -315,40 +344,34 @@ def lm_verify_paged(params: dict, tokens: jax.Array, cache: PagedKVCache,
     :func:`lm_decode_step_paged` calls."""
     x = embed(params["embed"], tokens, cfg.onehot_embed)
 
-    def body(h, layer):
-        pl, ck, cv = layer
+    def layer(pl, h, cl, moe):
         z = rms_norm(pl["ln1"], h, cfg.norm_eps)
-        attn, new_c = attention_verify_paged(
-            pl["attn"], z, PagedKVCache(ck, cv), table, pos, cfg)
+        attn, new_c = attention_verify_paged(pl["attn"], z, cl, table, pos,
+                                             cfg)
         hh = h + attn
-        zz = rms_norm(pl["ln2"], hh, cfg.norm_eps)
-        if _is_moe(cfg):
-            y, _ = moe_fwd(pl["moe"], zz, cfg, use_kernel=cfg.use_flash)
-        else:
-            y = mlp(pl["mlp"], zz)
+        y, _ = _ffn(pl, rms_norm(pl["ln2"], hh, cfg.norm_eps), cfg, moe)
         return hh + y, new_c
 
-    x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache.k, cache.v),
-                                unroll=cfg.unroll_scans)
+    x, new_cache = _scan_stacks(params, x, cache, cfg, layer)
     x = rms_norm(params["ln_f"], x, cfg.norm_eps)
-    return _unembed(params, x, cfg), PagedKVCache(new_cache.k, new_cache.v)
+    return _unembed(params, x, cfg), new_cache
 
 
-def lm_insert_prefill_paged(cache: PagedKVCache, dense: KVCache,
-                            table_row: jax.Array, slot, cfg: ModelConfig
-                            ) -> PagedKVCache:
+def lm_insert_prefill_paged(cache, dense, table_row: jax.Array, slot,
+                            cfg: ModelConfig):
     """Scatter a single request's contiguous prefill cache (ring-aligned
-    [L, 1, cap, kvH, hd], from :func:`lm_prefill`) into the pool blockwise.
+    [L, 1, cap, ...], from :func:`lm_prefill`) into the pool blockwise.
     Sink-padded table entries receive the (zero) tail blocks — harmless, the
     sink is never unmasked.  ``slot`` is unused (the transformer keeps no
     per-slot state beyond KV); hybrid's variant writes Mamba states there."""
     del slot
     nblk = table_row.shape[0]
-    bs = cache.k.shape[2]
-    lead = cache.k.shape[0]
 
     def scatter(pool, full):
-        blocks = full[:, 0].reshape(lead, nblk, bs, *pool.shape[3:])
+        blocks = full[:, 0].reshape(pool.shape[0], nblk, *pool.shape[2:])
         return pool.at[:, table_row].set(blocks.astype(pool.dtype))
 
-    return PagedKVCache(scatter(cache.k, dense.k), scatter(cache.v, dense.v))
+    leaves, tree = jax.tree.flatten(cache)
+    return jax.tree.unflatten(tree, [
+        scatter(pool, full)
+        for pool, full in zip(leaves, jax.tree.leaves(dense))])

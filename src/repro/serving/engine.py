@@ -413,36 +413,42 @@ class ServingEngine:
 
     def _export_kv(self, req: Request) -> Optional[Tuple[np.ndarray, ...]]:
         # only chunk-capable (pure-attention) pools migrate prefix KV; the
-        # hybrid never parks a partially-prefilled request
-        if not hasattr(self.cache, "k"):
+        # hybrid never parks a partially-prefilled request.  Every leaf of
+        # such a pool is layer-stacked blocks [L, num_blocks, bs, ...]
+        if self._prefill_chunk is None:
             return None
         blocks = self.alloc.blocks_of(req.rid)
         need = self.alloc.blocks_for_tokens(req.prefilled)
         if len(blocks) < need:
             return None
         idx = jnp.asarray(blocks[:need], jnp.int32)
-        return (np.asarray(self.cache.k[:, idx]),
-                np.asarray(self.cache.v[:, idx]))
+        return tuple(np.asarray(a[:, idx])
+                     for a in jax.tree.leaves(self.cache))
 
     def _import_kv(self, req: Request, kv) -> bool:
-        if not hasattr(self.cache, "k"):
+        if self._prefill_chunk is None:
             return False
-        k_np, v_np = kv
-        nblk = k_np.shape[1]
+        leaves, tree = jax.tree.flatten(self.cache)
+        nblk = kv[0].shape[1]
         if nblk > self.max_blocks or req.prompt_len + 1 > self.cap:
             # victim had a larger ring than ours: the prefix cannot resume
             # chunk-aligned here — recompute through the dense prefill
             return False
-        if k_np.shape[2] != self.block_size or \
+        if kv[0].shape[2] != self.block_size or \
                 not self.alloc.can_allocate(nblk * self.block_size,
                                             req.rid):
             return False                     # thief pool full: recompute
         self.alloc.ensure(req.rid, nblk * self.block_size)
         idx = jnp.asarray(self.alloc.blocks_of(req.rid)[:nblk], jnp.int32)
-        self.cache = type(self.cache)(
-            self.cache.k.at[:, idx].set(jnp.asarray(k_np)),
-            self.cache.v.at[:, idx].set(jnp.asarray(v_np)))
+        self.cache = jax.tree.unflatten(tree, [
+            a.at[:, idx].set(jnp.asarray(b)) for a, b in zip(leaves, kv)])
         return True
+
+    def _copy_block(self, old: int, new: int) -> None:
+        """Copy pool block ``old`` to ``new`` in every layer (a fork)."""
+        self.cache = jax.tree.map(lambda a: a.at[:, new].set(a[:, old]),
+                                  self.cache)
+        self._table_dirty = True
 
     def _table_row(self, rid: int) -> np.ndarray:
         return self.alloc.table_row(rid, self.max_blocks)
@@ -539,11 +545,7 @@ class ServingEngine:
                 if not self._preempt_for(req):
                     return False
         if fork is not None:
-            old, new = fork
-            self.cache = type(self.cache)(
-                self.cache.k.at[:, new].set(self.cache.k[:, old]),
-                self.cache.v.at[:, new].set(self.cache.v[:, old]))
-            self._table_dirty = True
+            self._copy_block(*fork)
         return True
 
     # -- speculative decoding primitives --------------------------------------
@@ -572,11 +574,7 @@ class ServingEngine:
                 self.alloc.truncate(req.rid, max(pos + 1, before))
                 return False
             if fork is not None:
-                old, new = fork
-                self.cache = type(self.cache)(
-                    self.cache.k.at[:, new].set(self.cache.k[:, old]),
-                    self.cache.v.at[:, new].set(self.cache.v[:, old]))
-                self._table_dirty = True
+                self._copy_block(*fork)
         return True
 
     def _apply_accepted(self, slot: int, accepted: List[int]

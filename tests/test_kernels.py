@@ -11,6 +11,8 @@ except ImportError:                     # optional dep: deterministic fallback
 
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import mha_ref
+from repro.kernels.mla_decode.ops import mla_decode
+from repro.kernels.mla_decode.ref import mla_decode_ref
 from repro.kernels.moe_gmm.ops import grouped_swiglu
 from repro.kernels.moe_gmm.ref import grouped_swiglu_ref
 from repro.kernels.prefix_scan.ops import prefix_scan
@@ -133,6 +135,23 @@ def test_flash_attention_kv_valid_decode():
 
 
 # ----------------------------------------------------------------- moe gmm
+# ----------------------------------------------------------- MLA decode
+@pytest.mark.parametrize("b,t,h,w,rank,bk", [
+    (3, 200, 16, 96, 64, 64),       # padded tail block, H = 16
+    (2, 256, 4, 40, 32, 128),       # whole blocks
+])
+def test_mla_decode_vs_ref(b, t, h, w, rank, bk):
+    """Per-sequence valid counts (1, part of a block, the whole view): the
+    blocks past each count are skipped, and masked where partly valid."""
+    k = jax.random.split(jax.random.PRNGKey(3), 2)
+    q = jax.random.normal(k[0], (b, h, w))
+    lat = jax.random.normal(k[1], (b, t, w))
+    valid = jnp.asarray([1, t // 2 + 3, t][:b], jnp.int32)
+    got = mla_decode(q, lat, valid, rank=rank, scale=0.2, bk=bk)
+    want = mla_decode_ref(q, lat, valid, rank=rank, scale=0.2)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
 @pytest.mark.parametrize("e,c,d,f", [(4, 64, 32, 64), (2, 100, 16, 48),
                                      (8, 16, 128, 256), (1, 8, 8, 8)])
 def test_grouped_swiglu_vs_ref(e, c, d, f):

@@ -15,6 +15,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels.flash_attention.ops import flash_attention
+from repro.kernels.mla_decode.ops import mla_decode
 from repro.kernels.moe_gmm.ops import grouped_swiglu
 from repro.kernels.prefix_scan.ops import prefix_scan
 from repro.kernels.wkv6.ops import wkv6
@@ -72,6 +73,16 @@ def test_flash_decode_compiles(spec):
              spec((b,), jnp.int32))
 
 
+def test_mla_decode_compiles(spec):
+    """deepseek-v2-lite's absorbed decode: 16 heads over 576-value latents
+    (512 of them the values), the longgen cell's 32 rows and 4096 ring."""
+    cfg = get_config("deepseek-v2-lite")
+    h, w, r = cfg.num_heads, cfg.mla_latent_width, cfg.kv_lora_rank
+    _compile(lambda q, lat, n: mla_decode(q, lat, n, rank=r, scale=0.1,
+                                          interpret=False),
+             spec((32, h, w)), spec((32, 4096, w)), spec((32,), jnp.int32))
+
+
 @pytest.mark.parametrize("t", [64, 128])
 def test_wkv6_compiles(spec, t):
     cfg = get_config("rwkv6-3b")
@@ -124,6 +135,37 @@ def test_qwen2_paged_decode_step_compiles(spec, one_chip, monkeypatch):
     mem = compiled.memory_analysis()
     # the output pool is a fresh buffer: arguments, output and temporaries
     # are all live while the step runs
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert live < V5E_HBM_BYTES, live
+
+
+def test_deepseek_v2_lite_paged_decode_step_compiles(spec, monkeypatch):
+    """One deepseek-v2-lite paged decode step with kernels on, at the
+    longgen cell's serving shape: 32 rows, 4096-token rings of 16-token
+    blocks over the latent pool, 8 held experts of 64."""
+    from repro.models import build_model
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.clear_caches()
+    model = build_model(get_config("deepseek-v2-lite").replace(
+        use_flash=True))
+    b, s_max, bs = 32, 4096, 16
+    nblk = b * s_max // bs + 1
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: spec(a.shape, a.dtype), tree)
+
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(
+        lambda: model.init_paged_cache(b, nblk, bs)))
+    compiled = _compile(model.decode_step_paged, params,
+                        spec((b, 1), jnp.int32), cache,
+                        spec((b, s_max // bs), jnp.int32),
+                        spec((b,), jnp.int32))
+    jax.clear_caches()
+    text = compiled.as_text()
+    assert "mla_decode_pallas" in text and "grouped_swiglu_pallas" in text
+    mem = compiled.memory_analysis()
     live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert live < V5E_HBM_BYTES, live
