@@ -22,8 +22,8 @@ Masking knobs (all composable):
   into VMEM blocks one element wide).
 
 Forward only: the training path uses XLA attention (or this kernel under
-``jax.checkpoint`` recomputation); serving uses it directly — prefill via
-the causal path, decode via ``causal=False`` + ``kv_valid``.
+``jax.checkpoint`` recomputation); serving uses it for prefill via the
+causal path.  Single-token decode has a kernel of its own (``decode.py``).
 """
 from __future__ import annotations
 

@@ -1,10 +1,12 @@
-"""Public wrapper: model layout [B, S, H, d] in/out, padding, GQA.
+"""Public wrappers: model layout [B, S, H, d] in/out, padding, GQA.
 
-``flash_attention`` is the name the model/serving layer imports; the raw
-grid kernel is ``kernel.flash_attention_pallas`` (kernel-layout
-[B, H, S, d]).  See the kernel docstring for the masking knobs
-(``q_offset`` for s≠t causal alignment, ``kv_valid`` for decode over a
-partially-filled cache).
+``flash_attention`` is the name the model/serving layer imports for
+prefill and training; the raw grid kernel is
+``kernel.flash_attention_pallas`` (kernel-layout [B, H, S, d]).  See the
+kernel docstring for the masking knobs (``q_offset`` for s≠t causal
+alignment, ``kv_valid`` for decode over a partially-filled cache).
+``flash_decode`` is single-token decode through
+``decode.flash_attention_pallas_decode``.
 """
 from __future__ import annotations
 
@@ -14,9 +16,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from .decode import (decode_kv_dtype, decode_tile,
+                     flash_attention_pallas_decode)
 from .kernel import flash_attention_pallas
 
-__all__ = ["flash_attention"]
+__all__ = ["flash_attention", "flash_decode"]
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "scale",
@@ -55,3 +59,31 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                                  q_offset=q_offset)
     out = out[:, :, :s]
     return jnp.moveaxis(out, 1, 2)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
+                 kv_valid: jax.Array, *, scale: Optional[float] = None,
+                 interpret: Optional[bool] = None) -> jax.Array:
+    """q: [B, 1, H, d]; k, v: [B, T, Hkv, d]; kv_valid: [B] int32 valid kv
+    positions per sequence → [B, 1, H, d].  Query head ``h`` reads kv head
+    ``h // (H // Hkv)``; positions ``>= kv_valid[b]`` are masked (and the
+    kernel fetches none of their tiles)."""
+    b, _, h, d = q.shape
+    t, hkv = k.shape[1], k.shape[2]
+    dt = decode_kv_dtype(hkv, d, k.dtype)
+    k, v = k.astype(dt), v.astype(dt)
+    bk = decode_tile(t, hkv * d, dt.itemsize)
+    pad = (-t) % bk
+    if pad:
+        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    # free reshapes: row t*Hkv + g is token t's head g, as the cache lays
+    # it out
+    tp = t + pad
+    out = flash_attention_pallas_decode(
+        q.reshape(b, hkv, h // hkv, d), k.reshape(b, tp * hkv, d),
+        v.reshape(b, tp * hkv, d), jnp.minimum(kv_valid, t).astype(jnp.int32),
+        scale=d ** -0.5 if scale is None else scale, bk=bk,
+        interpret=interpret)
+    return out.reshape(b, 1, h, d)

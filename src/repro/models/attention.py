@@ -226,16 +226,16 @@ def _attend_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     s_max = k_cache.shape[1]
     hd = cfg.resolved_head_dim
     if cfg.use_flash:
-        # Flash decode: one query row, non-causal, per-sequence valid-kv
-        # count.  Cache slots are filled 0..pos before wrap and the whole
-        # ring is live after (window eviction == ring eviction), so the
-        # count is min(pos+1, ring size) — slot order does not matter
-        # (RoPE is applied at projection, attention is kv-permutation
-        # invariant).
-        from ..kernels.flash_attention.ops import flash_attention
+        # Flash decode: one query row per sequence, per-sequence valid-kv
+        # count; the kernel reads each live KV tile once for all query
+        # heads and fetches no tile past the count.  Cache slots are
+        # filled 0..pos before wrap and the whole ring is live after
+        # (window eviction == ring eviction), so the count is
+        # min(pos+1, ring size) — slot order does not matter (RoPE is
+        # applied at projection, attention is kv-permutation invariant).
+        from ..kernels.flash_attention.ops import flash_decode
         kv_valid = jnp.minimum(pos_vec + 1, s_max).astype(jnp.int32)
-        return flash_attention(q, k_cache, v_cache, kv_valid,
-                               causal=False, scale=hd ** -0.5)
+        return flash_decode(q, k_cache, v_cache, kv_valid, scale=hd ** -0.5)
     # valid positions per sequence: j <= pos (within window when sliding)
     j = jnp.arange(s_max)[None, :]
     pcol = pos_vec[:, None]

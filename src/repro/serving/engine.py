@@ -44,6 +44,7 @@ from ..core.device.request_scheduler import (AdmissionRejected, BatchPlan,
                                              ContinuousBatcher, Request,
                                              RequestState)
 from ..core.strategy import MergePolicy
+from ..kernels.flash_attention.decode import decode_kv_dtype, decode_tile
 from ..models.model_zoo import Model
 from .paged_kv import (BlockAllocator, PoolExhausted, SINK_BLOCK,
                        prefix_block_keys)
@@ -139,6 +140,11 @@ class ServingEngine:
         #: no KV ring at all
         self.cap = s_max if cfg.sliding_window is None \
             else min(s_max, cfg.sliding_window)
+        #: tokens per KV tile of the flash decode kernel over this ring
+        #: (``serve.decode`` stat ``kv_tiles``)
+        kvh, hd = cfg.num_kv_heads, cfg.resolved_head_dim
+        self.kv_tile = decode_tile(
+            self.cap, kvh * hd, decode_kv_dtype(kvh, hd, cfg.dtype).itemsize)
         # A full-attention ring cannot evict: a request whose
         # prompt + budget exceeds the capacity wraps and corrupts its own
         # earliest KV (models/attention.py paged-prefill contract requires
@@ -852,10 +858,13 @@ class ServingEngine:
         if not active:
             return len(handled)
         # live_tokens: the positions the rows attend over, the sum the
-        # attention's bytes and FLOPs are linear in
+        # attention's bytes and FLOPs are linear in; kv_tiles: the KV tiles
+        # the flash decode kernel fetches for them
         live = np.minimum(self.slot_pos[active] + 1, self.cap)
         with TraceAnnotation("serve.decode", rows=len(active),
-                             live_tokens=int(live.sum())) as span:
+                             live_tokens=int(live.sum()),
+                             kv_tiles=int((-(-live // self.kv_tile)).sum())
+                             ) as span:
             pos_vec = jnp.asarray(self.slot_pos, jnp.int32)
             # a copy: on the CPU ``jnp.asarray`` would alias the buffer the
             # commit below writes into

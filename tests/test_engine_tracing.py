@@ -36,18 +36,19 @@ def _serve_spans(path: Path):
 
 
 def _traced_serve(tmp_path, chunk, spec=False, lens=(8, 8, 8),
-                  max_batch=2):
+                  max_batch=2, s_max=64):
     """Serve ``lens`` prompts on a fresh tiny paged engine (fresh model
     functions, so every program compiles once here) under a profiler trace,
     recording what each program call and each executed chunk saw."""
     cfg = scale_down(get_config("qwen2-1.5b"))
     model = build_model(cfg)
     params = model.init(KEY)
-    eng = ServingEngine(model, params, max_batch=max_batch, s_max=64,
+    eng = ServingEngine(model, params, max_batch=max_batch, s_max=s_max,
                         prefill_chunk=chunk, prefill_token_budget=16,
                         speculator=Speculator(model, params, k=2)
                         if spec else None)
-    rec = {"chunks": [], "decode": [], "clock": [], "decode_args": None}
+    rec = {"chunks": [], "decode": [], "lives": [], "clock": [],
+           "decode_args": None}
 
     complete = eng.batcher.complete_prefill_chunk
 
@@ -59,8 +60,9 @@ def _traced_serve(tmp_path, chunk, spec=False, lens=(8, 8, 8),
 
     def on_decode(*args):
         rows = [r for r in eng.slot_req if r is not None]
-        rec["decode"].append((len(rows), sum(
-            r.prompt_len + len(eng.outputs[r.rid]) for r in rows)))
+        lives = [r.prompt_len + len(eng.outputs[r.rid]) for r in rows]
+        rec["decode"].append((len(rows), sum(lives)))
+        rec["lives"].append(lives)
         rec["decode_args"] = rec["decode_args"] or args
         return decode(*args)
 
@@ -105,6 +107,21 @@ def test_step_spans_enclose_phases_in_order(tmp_path, chunk):
     # the decode span's rows and live tokens: the slots the call stepped
     assert [(p[3]["rows"], p[3]["live_tokens"])
             for p in phases if p[2] == "serve.decode"] == rec["decode"]
+
+
+def test_decode_span_counts_the_kv_tiles_flash_decode_fetches(tmp_path):
+    """``kv_tiles``: the flash decode kernel's tiles that the rows' live
+    lengths span, sum(ceil(min(pos+1, cap) / bk)), at mixed depths on both
+    sides of a tile boundary."""
+    _, eng, _, rec, spans = _traced_serve(tmp_path, None, lens=(8, 509, 700),
+                                          max_batch=3, s_max=1024)
+    assert eng.kv_tile == 512
+    tiles = [p[3]["kv_tiles"] for p in spans if p[2] == "serve.decode"]
+    want = [sum(-(-n // 512) for n in lives) for lives in rec["lives"]]
+    assert tiles == want
+    # a row of 512 live tokens is one tile, a row of 701 two
+    assert {512, 701} <= {n for lives in rec["lives"] for n in lives}
+    assert any(t > len(lives) for t, lives in zip(tiles, rec["lives"]))
 
 
 def test_commit_reads_the_steps_tokens_back_once(tmp_path):

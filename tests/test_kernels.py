@@ -9,7 +9,8 @@ try:
 except ImportError:                     # optional dep: deterministic fallback
     from _hypothesis_fallback import given, settings, st
 
-from repro.kernels.flash_attention.ops import flash_attention
+from repro.kernels.flash_attention.decode import decode_tile
+from repro.kernels.flash_attention.ops import flash_attention, flash_decode
 from repro.kernels.flash_attention.ref import mha_ref
 from repro.kernels.mla_decode.ops import mla_decode
 from repro.kernels.mla_decode.ref import mla_decode_ref
@@ -132,6 +133,50 @@ def test_flash_attention_kv_valid_decode():
                 jnp.moveaxis(v, 2, 1), causal=False, kv_valid=kv_valid),
         1, 2)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5)
+
+
+# The decode kernel's tile is 512 tokens at these shapes: valid counts of
+# 1, exactly one tile, one past it and the whole ring, a ring that is not a
+# whole number of tiles, and rows that end inside the first tile (their
+# later tiles are neither fetched nor computed).
+@pytest.mark.parametrize("t,h,hkv,d,kv_valid,dtype,atol", [
+    (1024, 12, 2, 128, [1, 512, 513, 1024], jnp.float32, 2e-5),  # qwen2
+    (1024, 12, 2, 128, [1, 512, 513, 1024], jnp.bfloat16, 1e-2),
+    (700, 4, 4, 64, [700, 3, 512, 513], jnp.float32, 2e-5),      # MHA
+    (600, 8, 1, 128, [600, 1, 64, 513], jnp.float32, 2e-5),      # MQA
+    (1024, 12, 2, 64, [3, 17, 100, 1024], jnp.float32, 2e-5),
+    (1024, 16, 8, 128, [2, 511, 40, 9], jnp.bfloat16, 1e-2),
+    (300, 6, 3, 128, [300, 1, 299, 150], jnp.bfloat16, 1e-2),    # odd kvH
+    (512, 12, 2, 128, [512, 7, 300, 1], jnp.float16, 1e-2),
+], ids=["group6", "group6-bf16", "mha-hd64-padded", "mqa-padded",
+        "hd64-first-tile", "kv8-bf16-first-tile", "kv3-bf16", "group6-f16"])
+def test_flash_decode_vs_ref(t, h, hkv, d, kv_valid, dtype, atol):
+    b = len(kv_valid)
+    ks = jax.random.split(jax.random.PRNGKey(9), 3)
+    q = jax.random.normal(ks[0], (b, 1, h, d), dtype)
+    k = jax.random.normal(ks[1], (b, t, hkv, d), dtype)
+    v = jax.random.normal(ks[2], (b, t, hkv, d), dtype)
+    kv_valid = jnp.asarray(kv_valid, jnp.int32)
+    got = flash_decode(q, k, v, kv_valid)
+    assert got.shape == q.shape and got.dtype == dtype
+    ref = jnp.moveaxis(
+        mha_ref(jnp.moveaxis(q, 2, 1), jnp.moveaxis(k, 2, 1),
+                jnp.moveaxis(v, 2, 1), causal=False, kv_valid=kv_valid),
+        1, 2)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(ref, np.float32), atol=atol)
+
+
+@pytest.mark.parametrize("cap,width,itemsize,bk", [
+    (4096, 256, 2, 512),        # qwen2-1.5b
+    (4096, 1024, 2, 512),       # qwen3-8b: a 1 MiB tile
+    (4096, 2048, 2, 256),
+    (4096, 1024, 4, 256),
+    (100, 256, 2, 128),         # short ring: cut to a power of two
+    (5, 256, 2, 16),
+])
+def test_decode_tile(cap, width, itemsize, bk):
+    assert decode_tile(cap, width, itemsize) == bk
 
 
 # ----------------------------------------------------------------- moe gmm

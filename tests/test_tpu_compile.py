@@ -169,3 +169,40 @@ def test_deepseek_v2_lite_paged_decode_step_compiles(spec, monkeypatch):
     live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert live < V5E_HBM_BYTES, live
+
+
+def test_qwen2_longgen_decode_step_runs_the_decode_kernel(spec, monkeypatch):
+    """One full-width qwen2-1.5b paged decode step at the longgen cell's
+    shape (32 rows, 4096-token rings of 16-token blocks) runs the flash
+    decode kernel under a name the roofline reader matches, hands it the
+    gathered K/V without a relayout copy, and fits one chip."""
+    from repro.models import build_model
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.clear_caches()
+    model = build_model(QWEN2.replace(use_flash=True))
+    b, s_max, bs = 32, 4096, 16
+    nblk = b * s_max // bs + 1
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: spec(a.shape, a.dtype), tree)
+
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(
+        lambda: model.init_paged_cache(b, nblk, bs)))
+    compiled = _compile(model.decode_step_paged, params,
+                        spec((b, 1), jnp.int32), cache,
+                        spec((b, s_max // bs), jnp.int32),
+                        spec((b,), jnp.int32))
+    jax.clear_caches()
+    text = compiled.as_text()
+    kernel = "flash_attention_pallas_decode"
+    # bench/metrics/flash_decode_roofline.longgen.py matches this substring
+    assert "flash_attention_pallas" in kernel and kernel in text
+    # the [B, cap*kvH, hd] view the kernel reads is only ever a bitcast
+    view = f"bf16[{b},{s_max * QWEN2.num_kv_heads},{QWEN2.head_dim}]"
+    made = [ln for ln in text.splitlines() if f"= {view}" in ln]
+    assert made and all(" bitcast(" in ln for ln in made), made
+    mem = compiled.memory_analysis()
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert live < V5E_HBM_BYTES, live
