@@ -87,7 +87,7 @@ def serving_scheduler() -> None:
 
 def kernel_microbench() -> None:
     """interpret-mode kernels vs their jnp oracles (correct-path cost on
-    CPU; the TPU perf story lives in the roofline analysis)."""
+    CPU; speed is measured on the chip by ``bench/``)."""
     import jax
     import jax.numpy as jnp
     from repro.kernels.prefix_scan.ops import prefix_scan

@@ -67,7 +67,7 @@ class ModelConfig:
     #: makes prefill+decode bit-consistent with the full forward (capacity
     #: competition is a whole-batch function, which a single decode step
     #: cannot see).  Set False to study capacity pressure / dead tasks
-    #: (hillclimb + dryrun dispatch cells do).
+    #: (launch/train.py does).
     moe_dropless: bool = True
     router_aux_coef: float = 0.01
     # hybrid (attention : SSM interleave, Jamba-style superblocks)

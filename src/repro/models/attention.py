@@ -4,11 +4,11 @@ Latent attention (MLA, ``cfg.is_mla``) takes the same entry points: its
 cache holds one latent per token instead of per-head K and V (see the MLA
 section below).
 
-The decode path supports a sequence-sharded KV cache (long-context): the
-attention below is written as plain einsums + softmax so XLA's SPMD
-partitioner inserts the collectives; the hand-optimized two-pass
-flash-decode variant lives in ``kernels/flash_attention`` and in
-``distributed.py`` (used during the perf hillclimb).
+Serving reads a paged pool through per-request block tables: one
+:func:`attention_paged` step covers decode, speculative verify and chunked
+prefill.  Without ``cfg.use_flash`` attention is plain einsums + softmax;
+with it, prefill and decode run the kernels in ``kernels/flash_attention``
+(and ``kernels/mla_decode`` for latent decode).
 """
 from __future__ import annotations
 
@@ -22,9 +22,8 @@ from .layers import (apply_rope, apply_rope_inv, init_linear, init_rms_norm,
                      linear, rms_norm, yarn_inv_freq, yarn_mscale)
 
 __all__ = ["init_attention", "attention_fwd", "attention_decode", "KVCache",
-           "PagedKVCache", "attention_decode_paged",
-           "attention_prefill_chunk_paged", "attention_verify_paged",
-           "init_paged_kv_cache", "LatentKVCache", "LatentPagedCache"]
+           "PagedKVCache", "attention_paged", "init_paged_kv_cache",
+           "LatentKVCache", "LatentPagedCache"]
 
 
 class KVCache(NamedTuple):
@@ -272,115 +271,63 @@ def attention_decode(p: dict, x: jax.Array, cache: KVCache, pos: jax.Array,
     return y, KVCache(k_cache, v_cache)
 
 
-def attention_decode_paged(p: dict, x: jax.Array, cache: PagedKVCache,
-                           table: jax.Array, pos: jax.Array,
-                           cfg: ModelConfig) -> tuple[jax.Array, PagedKVCache]:
-    """One-token decode reading/writing K/V through per-request block tables
-    over the shared physical pool.  ``table``: [B, max_blocks] int32 physical
-    block ids (logical block ``j`` of sequence ``b`` at ``table[b, j]``;
-    unallocated entries point at the sink block, whose contents are never
-    unmasked).  Semantics — including the sliding-window ring — match
-    :func:`attention_decode` over a contiguous cache of capacity
-    ``cap = max_blocks * block_size``: the gathered logical view has the
-    same width, mask and values, so fp32 decode is bit-identical."""
-    if cfg.is_mla:
-        return _mla_decode_paged(p, x, cache, table, pos, cfg)
-    b = x.shape[0]
-    bs = cache.k.shape[1]
-    cap = table.shape[1] * bs
-    pos_vec = jnp.broadcast_to(jnp.asarray(pos).reshape(-1), (b,))
-    positions = pos_vec[:, None]
-    q, k_new, v_new = _project_qkv(p, x, cfg, positions)
-    # ring slot -> (physical block, offset); empty batch slots hit the sink
-    slot = pos_vec % cap
-    blk = jnp.take_along_axis(table, (slot // bs)[:, None], axis=1)[:, 0]
-    off = slot % bs
-    k_pool = cache.k.at[blk, off].set(k_new[:, 0].astype(cache.k.dtype))
-    v_pool = cache.v.at[blk, off].set(v_new[:, 0].astype(cache.v.dtype))
-    # gather the per-sequence logical view [B, cap, kvH, hd]
-    k_log = k_pool[table].reshape(b, cap, *cache.k.shape[2:])
-    v_log = v_pool[table].reshape(b, cap, *cache.v.shape[2:])
-    out = _attend_decode(q, k_log, v_log, pos_vec, cfg)
-    y = linear(p["wo"], out.reshape(b, 1, -1))
-    return y, PagedKVCache(k_pool, v_pool)
+def _paged_write(pool: jax.Array, table: jax.Array, rows: jax.Array,
+                 new: jax.Array) -> jax.Array:
+    """Scatter ``new`` [B, c, ...] into ``pool`` [num_blocks, bs, ...] at
+    absolute positions ``rows`` [B, c]: ring slot ``s = rows % cap`` of
+    sequence ``b`` lives at ``pool[table[b, s // bs], s % bs]`` (empty batch
+    slots' table rows point at the sink)."""
+    bs = pool.shape[1]
+    slot = rows % (table.shape[1] * bs)
+    blk = jnp.take_along_axis(table, slot // bs, axis=1)
+    return pool.at[blk.reshape(-1), (slot % bs).reshape(-1)].set(
+        new.reshape(-1, *pool.shape[2:]).astype(pool.dtype))
 
 
-def attention_verify_paged(p: dict, x: jax.Array, cache: PagedKVCache,
-                           table: jax.Array, pos: jax.Array,
-                           cfg: ModelConfig) -> tuple[jax.Array, PagedKVCache]:
-    """Batched multi-token decode for speculative verification: ``c`` query
-    tokens per sequence at absolute positions ``pos[b] .. pos[b]+c-1``, each
-    batch row through its own block table.  The bottom-right-causal mask of
-    :func:`attention_prefill_chunk_paged` generalized to a batch: row ``i``
-    of sequence ``b`` attends logical columns ``j <= pos[b]+i`` (within the
-    sliding window), so with ``c == 1`` this is exactly
-    :func:`attention_decode_paged`'s masked path — which is what makes the
-    accepted tokens of a greedy verify bit-identical to sequential decode.
-    x: [B, c, D]; table: [B, max_blocks]; pos: [B] int32.  Requires
-    ``pos[b] + c <= cap`` for live rows (no ring wrap — the engine falls
-    back to plain decode near the wrap point); inactive batch slots are
-    routed to an all-sink table row, whose contents are garbage by design
-    and never read unmasked.  Always the masked XLA path, like chunked
-    prefill (the flash kernel's ``q_offset`` is static per shape)."""
-    if cfg.is_mla:
-        raise NotImplementedError("speculative verify has no MLA form")
+def _paged_view(pool: jax.Array, table: jax.Array) -> jax.Array:
+    """Gather each sequence's logical view [B, cap, ...] of ``pool``."""
+    b, n = table.shape
+    return pool[table].reshape(b, n * pool.shape[1], *pool.shape[2:])
+
+
+def attention_paged(p: dict, x: jax.Array, cache: PagedKVCache,
+                    table: jax.Array, pos: jax.Array,
+                    cfg: ModelConfig) -> tuple[jax.Array, PagedKVCache]:
+    """``c`` tokens per sequence at absolute positions ``pos[b] ..
+    pos[b]+c-1``, each batch row reading and writing K/V through its own
+    block table over the shared physical pool.  x: [B, c, D]; table:
+    [B, max_blocks] int32 physical block ids (logical block ``j`` of
+    sequence ``b`` at ``table[b, j]``; unallocated entries point at the sink
+    block, whose contents are never unmasked); pos: [] or [B].  Decode is
+    ``c == 1``, speculative verify ``c > 1`` over B rows, a prompt chunk
+    ``B == 1``.
+
+    At ``c == 1`` the gathered logical view has the width, mask and values
+    of :func:`attention_decode` over a contiguous cache of capacity
+    ``cap = max_blocks * block_size`` (sliding-window ring included), so
+    fp32 decode is bit-identical.  At ``c > 1`` row ``i`` attends logical
+    columns ``j <= pos[b]+i`` on the masked XLA path (the flash kernel's
+    ``q_offset`` is static per shape); with ``c == 1`` that is decode's
+    masked path, which makes a greedy verify's accepted tokens
+    bit-identical to sequential decode.  It requires ``pos[b] + c <= cap``
+    for live rows: no ring wrap, since the engine falls back to plain
+    decode or whole-prompt prefill near the wrap.  The mask needs no
+    window term there: the engine clamps ``cap`` to the window, so
+    ``j <= row`` already implies ``row - j < window``."""
     b, c, _ = x.shape
-    bs = cache.k.shape[1]
-    cap = table.shape[1] * bs
-    hd = cfg.resolved_head_dim
     pos_vec = jnp.broadcast_to(jnp.asarray(pos).reshape(-1), (b,))
-    rows = pos_vec[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]  # [B,c]
+    rows = pos_vec[:, None] + jnp.arange(c, dtype=pos_vec.dtype)   # [B, c]
+    if cfg.is_mla:
+        return _mla_paged(p, x, cache, table, rows, cfg)
     q, k_new, v_new = _project_qkv(p, x, cfg, rows)
-    slot = rows % cap
-    blk = jnp.take_along_axis(table, slot // bs, axis=1)       # [B, c]
-    off = slot % bs
-    k_pool = cache.k.at[blk, off].set(k_new.astype(cache.k.dtype))
-    v_pool = cache.v.at[blk, off].set(v_new.astype(cache.v.dtype))
-    k_log = k_pool[table].reshape(b, cap, *cache.k.shape[2:])
-    v_log = v_pool[table].reshape(b, cap, *cache.v.shape[2:])
-    j = jnp.arange(cap, dtype=jnp.int32)[None, None, :]
-    r = rows[:, :, None]
-    valid = j <= r
-    if cfg.sliding_window is not None:
-        valid &= r - j < cfg.sliding_window
-    out = _sdpa(q, k_log, v_log, valid, hd ** -0.5)
-    y = linear(p["wo"], out.reshape(b, c, -1))
-    return y, PagedKVCache(k_pool, v_pool)
-
-
-def attention_prefill_chunk_paged(p: dict, x: jax.Array, cache: PagedKVCache,
-                                  table_row: jax.Array, start: jax.Array,
-                                  cfg: ModelConfig
-                                  ) -> tuple[jax.Array, PagedKVCache]:
-    """Prefill one chunk of a single request's prompt against its paged KV:
-    query rows are absolute positions ``start .. start+c-1``; the chunk's
-    K/V are scattered into the request's blocks, then attention runs over
-    the full logical view (history + chunk) under a bottom-right causal
-    mask.  x: [1, c, D]; table_row: [max_blocks] int32; start: [] int32.
-    Requires ``start + c <= cap`` (no ring wrap mid-prompt — the engine
-    falls back to whole-prompt prefill otherwise).  Always uses the masked
-    XLA path: the flash kernel's ``q_offset`` is static, and recompiling per
-    chunk boundary would cost more than the chunk."""
-    if cfg.is_mla:
-        return _mla_prefill_chunk_paged(p, x, cache, table_row, start, cfg)
-    b, c, _ = x.shape
-    bs = cache.k.shape[1]
-    cap = table_row.shape[0] * bs
-    hd = cfg.resolved_head_dim
-    start = jnp.asarray(start, jnp.int32)
-    rows = start + jnp.arange(c, dtype=jnp.int32)
-    q, k_new, v_new = _project_qkv(p, x, cfg, rows[None, :])
-    blk = table_row[rows // bs]
-    off = rows % bs
-    k_pool = cache.k.at[blk, off].set(k_new[0].astype(cache.k.dtype))
-    v_pool = cache.v.at[blk, off].set(v_new[0].astype(cache.v.dtype))
-    k_log = k_pool[table_row][None].reshape(1, cap, *cache.k.shape[2:])
-    v_log = v_pool[table_row][None].reshape(1, cap, *cache.v.shape[2:])
-    j = jnp.arange(cap, dtype=jnp.int32)[None, None, :]  # logical col == pos
-    valid = j <= rows[None, :, None]
-    if cfg.sliding_window is not None:
-        valid &= rows[None, :, None] - j < cfg.sliding_window
-    out = _sdpa(q, k_log, v_log, valid, hd ** -0.5)
+    k_pool = _paged_write(cache.k, table, rows, k_new)
+    v_pool = _paged_write(cache.v, table, rows, v_new)
+    k_log, v_log = _paged_view(k_pool, table), _paged_view(v_pool, table)
+    if c == 1:
+        out = _attend_decode(q, k_log, v_log, rows[:, 0], cfg)
+    else:
+        valid = jnp.arange(k_log.shape[1])[None, None, :] <= rows[:, :, None]
+        out = _sdpa(q, k_log, v_log, valid, cfg.resolved_head_dim ** -0.5)
     y = linear(p["wo"], out.reshape(b, c, -1))
     return y, PagedKVCache(k_pool, v_pool)
 
@@ -529,40 +476,24 @@ def _mla_decode(p: dict, x: jax.Array, cache: LatentKVCache, pos,
     return y, LatentKVCache(c)
 
 
-def _mla_decode_paged(p: dict, x: jax.Array, cache: LatentPagedCache,
-                      table: jax.Array, pos, cfg: ModelConfig):
-    with jax.named_scope("mla"):
-        b = x.shape[0]
-        bs = cache.c.shape[1]
-        cap = table.shape[1] * bs
-        pos_vec = jnp.broadcast_to(jnp.asarray(pos).reshape(-1), (b,))
-        q_nope, q_rope, lat = _mla_project(p, x, cfg, pos_vec[:, None])
-        slot = pos_vec % cap
-        blk = jnp.take_along_axis(table, (slot // bs)[:, None], axis=1)[:, 0]
-        pool = cache.c.at[blk, slot % bs].set(lat[:, 0].astype(cache.c.dtype))
-        view = pool[table].reshape(b, cap, -1)
-        y = _mla_attend_latents(p, q_nope, q_rope, view, pos_vec, cfg)
-    return y, LatentPagedCache(pool)
-
-
-def _mla_prefill_chunk_paged(p: dict, x: jax.Array, cache: LatentPagedCache,
-                             table_row: jax.Array, start, cfg: ModelConfig):
-    """One prompt chunk: its latents go into the pool, then its queries
-    attend, in the expanded form, over every latent of the request's view
-    (earlier chunks' read back from the pool) under a bottom-right causal
-    mask.  Same contract as :func:`attention_prefill_chunk_paged`."""
+def _mla_paged(p: dict, x: jax.Array, cache: LatentPagedCache,
+               table: jax.Array, rows: jax.Array, cfg: ModelConfig):
+    """:func:`attention_paged` over the latent pool, at positions ``rows``
+    [B, c]: absorbed decode at ``c == 1``; at ``c > 1`` (a prompt chunk)
+    the queries attend, in the expanded form, over every latent of the
+    view."""
     with jax.named_scope("mla"):
         b, c, _ = x.shape
-        bs = cache.c.shape[1]
-        cap = table_row.shape[0] * bs
-        rows = jnp.asarray(start, jnp.int32) + jnp.arange(c, dtype=jnp.int32)
-        q_nope, q_rope, lat = _mla_project(p, x, cfg, rows[None, :])
-        pool = cache.c.at[table_row[rows // bs], rows % bs].set(
-            lat[0].astype(cache.c.dtype))
-        k, v = _mla_expand(p, pool[table_row].reshape(1, cap, -1), cfg)
-        valid = jnp.arange(cap, dtype=jnp.int32)[None, None, :] \
-            <= rows[None, :, None]
-        out = _sdpa(jnp.concatenate([q_nope, q_rope], -1), k, v, valid,
-                    _mla_softmax_scale(cfg))
-        y = linear(p["wo"], out.reshape(b, c, -1))
+        q_nope, q_rope, lat = _mla_project(p, x, cfg, rows)
+        pool = _paged_write(cache.c, table, rows, lat)
+        view = _paged_view(pool, table)
+        if c == 1:
+            y = _mla_attend_latents(p, q_nope, q_rope, view, rows[:, 0], cfg)
+        else:
+            k, v = _mla_expand(p, view, cfg)
+            valid = jnp.arange(view.shape[1])[None, None, :] \
+                <= rows[:, :, None]
+            out = _sdpa(jnp.concatenate([q_nope, q_rope], -1), k, v, valid,
+                        _mla_softmax_scale(cfg))
+            y = linear(p["wo"], out.reshape(b, c, -1))
     return y, LatentPagedCache(pool)

@@ -17,8 +17,8 @@ import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
 from .attention import (KVCache, PagedKVCache, attention_decode,
-                        attention_decode_paged, attention_fwd,
-                        init_attention, init_kv_cache, init_paged_kv_cache)
+                        attention_fwd, attention_paged, init_attention,
+                        init_kv_cache, init_paged_kv_cache)
 from .layers import (dtype_of, embed, init_embedding, init_linear, init_mlp,
                      init_rms_norm, linear, mlp, rms_norm)
 from .moe import init_moe, moe_fwd
@@ -270,8 +270,8 @@ def _superblock_decode_paged(p: dict, x, kv: PagedKVCache, conv, h, table,
     for layer in p["layers"]:
         z = rms_norm(layer["ln1"], x, cfg.norm_eps)
         if "attn" in layer:
-            y, new_kv = attention_decode_paged(layer["attn"], z, kv, table,
-                                               pos, cfg)
+            y, new_kv = attention_paged(layer["attn"], z, kv, table, pos,
+                                        cfg)
             x = x + y
         else:
             st = MambaState(conv=conv[mi], h=h[mi])

@@ -90,18 +90,18 @@ def _build(cfg: ModelConfig) -> Model:
             init_paged_cache=lambda batch, nb, bs:
                 transformer.init_lm_paged_cache(cfg, nb, bs),
             decode_step_paged=lambda p, tok, cache, table, pos:
-                transformer.lm_decode_step_paged(p, tok, cache, table, pos,
-                                                 cfg),
+                transformer.lm_paged(p, tok, cache, table, pos, cfg),
             insert_prefill_paged=lambda cache, dense, row, slot:
                 transformer.lm_insert_prefill_paged(cache, dense, row, slot,
                                                     cfg),
             prefill_chunk_paged=lambda p, b, cache, row, start:
-                transformer.lm_prefill_chunk_paged(p, b, cache, row, start,
-                                                   cfg),
+                transformer.lm_paged(p, b["tokens"], cache, row[None],
+                                     jnp.asarray(start, jnp.int32)[None],
+                                     cfg, last_only=True),
             # speculative verify has no latent-attention (MLA) form
             verify_paged=None if cfg.is_mla else
             (lambda p, toks, cache, table, pos:
-                transformer.lm_verify_paged(p, toks, cache, table, pos, cfg)),
+                transformer.lm_paged(p, toks, cache, table, pos, cfg)),
         )
     if fam == "ssm":
         return Model(

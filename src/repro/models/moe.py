@@ -101,7 +101,7 @@ def _moe_fwd(p: dict, x: jax.Array, cfg: ModelConfig,
     # padding: ~E/(k·cf) more slots (mostly zeros) than droppy dispatch;
     # a tighter static bound cannot exist (routing may send every token to
     # one expert), so throughput studies that can tolerate drops opt out
-    # via moe_dropless=False (hillclimb/dryrun dispatch cells do).
+    # via moe_dropless=False (launch/train.py does).
     # Droppy: the configured capacity, clamped to the same T bound (slots
     # past it are dead space).
     cap = t if cfg.moe_dropless else min(moe_capacity(cfg, t), t)

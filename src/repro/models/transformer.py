@@ -20,17 +20,15 @@ import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
 from .attention import (KVCache, LatentPagedCache, attention_decode,
-                        attention_decode_paged, attention_fwd,
-                        attention_prefill_chunk_paged, attention_verify_paged,
-                        init_attention, init_kv_cache, init_paged_kv_cache)
+                        attention_fwd, attention_paged, init_attention,
+                        init_kv_cache, init_paged_kv_cache)
 from .layers import (dtype_of, embed, init_embedding, init_linear,
                      init_mlp, init_rms_norm, linear, mlp, rms_norm)
 from .moe import MoEStats, init_moe, moe_fwd
 
 __all__ = ["init_lm", "lm_forward", "lm_prefill", "lm_decode_step",
-           "init_lm_cache", "LMOutputs", "init_lm_paged_cache",
-           "lm_decode_step_paged", "lm_prefill_chunk_paged",
-           "lm_insert_prefill_paged", "lm_verify_paged"]
+           "init_lm_cache", "LMOutputs", "init_lm_paged_cache", "lm_paged",
+           "lm_insert_prefill_paged"]
 
 
 class LMOutputs(NamedTuple):
@@ -270,7 +268,7 @@ def lm_decode_step(params: dict, token: jax.Array, cache,
 
 
 # --------------------------------------------------------------------------
-# Paged KV: decode + chunked prefill through per-request block tables
+# Paged KV: decode, verify and chunked prefill through block tables
 # --------------------------------------------------------------------------
 
 def init_lm_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int):
@@ -286,75 +284,34 @@ def init_lm_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int):
     return _join(_stacked(cfg, one), cfg)
 
 
-def _block_decode_paged(p: dict, x: jax.Array, cache, table, pos, cfg, moe):
-    y_attn, new_cache = attention_decode_paged(
-        p["attn"], rms_norm(p["ln1"], x, cfg.norm_eps), cache, table, pos,
-        cfg)
-    h = x + y_attn
-    y, _ = _ffn(p, rms_norm(p["ln2"], h, cfg.norm_eps), cfg, moe)
-    return h + y, new_cache
+def lm_paged(params: dict, tokens: jax.Array, cache, table: jax.Array,
+             pos: jax.Array, cfg: ModelConfig, last_only: bool = False):
+    """Run ``tokens`` [B, c] through every layer at absolute positions
+    ``pos[b] .. pos[b]+c-1`` against the paged pool, K/V read and written
+    through ``table`` [B, max_blocks] (:func:`attention_paged`).  Returns
+    (logits, new pool): every row's [B, c, V], or with ``last_only`` the
+    last row's [B, 1, V].
 
-
-def lm_decode_step_paged(params: dict, token: jax.Array, cache,
-                         table: jax.Array, pos: jax.Array, cfg: ModelConfig):
-    """Paged decode: K/V read through ``table`` [B, max_blocks] instead of a
-    dense per-slot buffer.  Bit-identical (fp32) to :func:`lm_decode_step`
-    over a contiguous cache of the same logical capacity."""
-    x = embed(params["embed"], token, cfg.onehot_embed)
-    x, new_cache = _scan_stacks(
-        params, x, cache, cfg, lambda pl, h, cl, moe: _block_decode_paged(
-            pl, h, cl, table, pos, cfg, moe))
-    x = rms_norm(params["ln_f"], x, cfg.norm_eps)
-    return _unembed(params, x, cfg), new_cache
-
-
-def lm_prefill_chunk_paged(params: dict, batch: dict, cache,
-                           table_row: jax.Array, start: jax.Array,
-                           cfg: ModelConfig):
-    """Run one chunk of a single request's prompt (tokens [1, c]) against
-    its block table, scattering the chunk's K/V into the pool.  Returns
-    (last-position logits [1, 1, V], updated pool) — the logits only matter
-    on the final chunk (they seed the first generated token)."""
-    x = _embed_inputs(params, batch, cfg)
-
-    def layer(pl, h, cl, moe):
-        z = rms_norm(pl["ln1"], h, cfg.norm_eps)
-        attn, new_c = attention_prefill_chunk_paged(
-            pl["attn"], z, cl, table_row, start, cfg)
-        hh = h + attn
-        y, _ = _ffn(pl, rms_norm(pl["ln2"], hh, cfg.norm_eps), cfg, moe)
-        return hh + y, new_c
-
-    x, new_cache = _scan_stacks(params, x, cache, cfg, layer)
-    x = rms_norm(params["ln_f"], x, cfg.norm_eps)
-    logits = _unembed(params, x[:, -1:], cfg)
-    return logits, new_cache
-
-
-def lm_verify_paged(params: dict, tokens: jax.Array, cache,
-                    table: jax.Array, pos: jax.Array, cfg: ModelConfig):
-    """Speculative verification step: run ``c`` tokens per sequence
-    (``tokens`` [B, c] — the last accepted token followed by the draft's
-    proposals) through the paged cache at absolute positions
-    ``pos[b] .. pos[b]+c-1`` and return **all-position** logits [B, c, V]
-    (unlike :func:`lm_prefill_chunk_paged`, every row's argmax matters: row
-    ``i`` decides whether draft token ``i+1`` is accepted).  With dropless
-    MoE routing the per-token computation is independent of its batch
-    neighbours, so the logits match ``c`` sequential
-    :func:`lm_decode_step_paged` calls."""
+    Decode (``c == 1``) is bit-identical (fp32) to :func:`lm_decode_step`
+    over a contiguous cache of the same logical capacity.  Speculative
+    verify (``c > 1``, the last accepted token then the draft's proposals)
+    needs every row: row ``i`` decides whether draft token ``i+1`` is
+    accepted; with dropless MoE routing each token's computation is
+    independent of its batch neighbours, so the logits match ``c``
+    sequential decode steps.  A prompt chunk (``B == 1``) needs only its
+    last row, which seeds the first generated token on the final chunk."""
     x = embed(params["embed"], tokens, cfg.onehot_embed)
 
     def layer(pl, h, cl, moe):
         z = rms_norm(pl["ln1"], h, cfg.norm_eps)
-        attn, new_c = attention_verify_paged(pl["attn"], z, cl, table, pos,
-                                             cfg)
-        hh = h + attn
-        y, _ = _ffn(pl, rms_norm(pl["ln2"], hh, cfg.norm_eps), cfg, moe)
-        return hh + y, new_c
+        attn, new_c = attention_paged(pl["attn"], z, cl, table, pos, cfg)
+        h = h + attn
+        y, _ = _ffn(pl, rms_norm(pl["ln2"], h, cfg.norm_eps), cfg, moe)
+        return h + y, new_c
 
     x, new_cache = _scan_stacks(params, x, cache, cfg, layer)
     x = rms_norm(params["ln_f"], x, cfg.norm_eps)
-    return _unembed(params, x, cfg), new_cache
+    return _unembed(params, x[:, -1:] if last_only else x, cfg), new_cache
 
 
 def lm_insert_prefill_paged(cache, dense, table_row: jax.Array, slot,

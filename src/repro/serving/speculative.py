@@ -31,7 +31,7 @@ tasks in one storage without mixed-type comparisons.
 Correctness contract (greedy targets): the accepted stream is
 **bit-identical** to non-speculative decode.  The target verifies
 ``[last_token, d_1..d_k]`` in one batched bottom-right-causal step
-(``attention_verify_paged``); :func:`accept_longest_prefix` emits
+(``attention_paged``); :func:`accept_longest_prefix` emits
 ``t_0..t_matched`` where ``t_j`` is the target's greedy choice at position
 ``j`` — by induction each accepted token is exactly what sequential decode
 would have produced.  Rejected draft KV is rolled back through the paged

@@ -138,7 +138,7 @@ def param_specs(params, mesh: Mesh, expert_parallel: bool = True,
                 tensor_parallel: bool = True,
                 embed_replicated: bool = False):
     """PartitionSpec pytree matching ``params`` (works on ShapeDtypeStructs
-    too — the dry-run path)."""
+    too)."""
     return jax.tree_util.tree_map_with_path(
         lambda kp, x: spec_for_path(_path_str(kp), x.shape, mesh,
                                     expert_parallel, fsdp_axes,

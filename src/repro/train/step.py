@@ -1,7 +1,6 @@
 """Step functions: training (with microbatch gradient accumulation) and
 serving (prefill / decode).  These are the functions the launcher jits with
-explicit in/out shardings and the dry-run lowers against the production
-mesh.
+explicit in/out shardings.
 """
 from __future__ import annotations
 

@@ -1,8 +1,7 @@
 import os
 import sys
 
-# Tests run on the single real CPU device (the 512-device override is
-# exclusively for the dry-run, which sets it before its own imports).
+# Tests run on the single real CPU device.
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 

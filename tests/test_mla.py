@@ -145,7 +145,7 @@ def test_absorbed_decode_equals_expanded_attention():
         for t in range(s):
             got, dense = A.attention_decode(p, x[:, t:t + 1], dense,
                                             jnp.int32(t), c)
-            paged, pool = A.attention_decode_paged(
+            paged, pool = A.attention_paged(
                 p, x[:, t:t + 1], pool, table, jnp.asarray([t], jnp.int32),
                 c)
             for y in (got, paged):

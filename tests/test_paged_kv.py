@@ -300,14 +300,24 @@ def test_shared_prefix_decode_bit_exact_vs_private_copies():
 
 
 # ------------------------------------- fragmented-table decode vs dense
-@pytest.mark.parametrize("use_flash", [False, True],
-                         ids=["xla", "flash-decode"])
-def test_fragmented_block_table_decode_matches_dense(use_flash):
+@pytest.mark.parametrize(
+    "use_flash,c,window",
+    [(False, 1, None), (True, 1, None), (False, 4, None), (True, 4, None),
+     (False, 4, 32)],
+    ids=["xla", "flash-decode", "xla-verify", "flash-verify",
+         "xla-verify-window"])
+def test_fragmented_block_table_decode_matches_dense(use_flash, c, window):
     """Two requests whose blocks interleave in the pool (worst-case
-    fragmentation), decoding at different depths in one batch: the paged
-    gather must reproduce the dense contiguous decode bit-for-bit (fp32)."""
+    fragmentation), at different depths in one batch: ``c`` tokens per row
+    through the paged gather (decode at ``c == 1``, the verify shape at
+    ``c == 4``) must reproduce ``c`` dense contiguous decode steps
+    bit-for-bit (fp32) on one attention path.  Verify takes the masked XLA
+    path, so against the flash kernel's decode it agrees to fp32 rounding.
+    With ``window`` the sliding window is below the requested ``s_max``,
+    so the ring is clamped to the window."""
     cfg = scale_down(get_config("qwen2-1.5b")).replace(
-        dtype="float32", param_dtype="float32", use_flash=use_flash)
+        dtype="float32", param_dtype="float32", use_flash=use_flash,
+        sliding_window=window)
     m = build_model(cfg)
     params = m.init(KEY)
     bs, cap = 8, 32
@@ -320,8 +330,8 @@ def test_fragmented_block_table_decode_matches_dense(use_flash):
     alloc = BlockAllocator(num_blocks=2 * nblk + 1, block_size=bs)
     for tokens in range(bs, cap + 1, bs):
         for rid in (0, 1):
-            if tokens <= ((lens[rid] + bs - 1) // bs) * bs:
-                alloc.ensure(rid, min(tokens, lens[rid]))
+            if tokens <= ((lens[rid] + c - 1 + bs - 1) // bs) * bs:
+                alloc.ensure(rid, min(tokens, lens[rid] + c - 1))
     tables = [alloc.blocks_of(r) for r in (0, 1)]
     assert tables[0] != sorted(tables[0]) or \
         any(abs(a - b) > 1 for a, b in zip(tables[0], tables[0][1:])), \
@@ -330,21 +340,32 @@ def test_fragmented_block_table_decode_matches_dense(use_flash):
     pool = m.init_paged_cache(2, 2 * nblk + 1, bs)
     denses = []
     for rid, t in enumerate(toks):
-        _, dense = m.prefill(params, {"tokens": t}, cap)
+        _, dense = m.prefill(params, {"tokens": t}, 2 * cap if window
+                             else cap)
         denses.append(dense)
         row = jnp.asarray(alloc.table_row(rid, nblk))
         pool = m.insert_prefill_paged(pool, dense, row, rid)
 
     batch_cache = jax.tree.map(lambda a, b: jnp.concatenate([a, b], axis=1),
                                denses[0], denses[1])
-    tok = jnp.asarray([[3], [5]], jnp.int32)
+    tok = jnp.asarray([[3, 7, 11, 2], [5, 1, 9, 4]], jnp.int32)[:, :c]
     pos = jnp.asarray(lens, jnp.int32)
-    ref, _ = m.decode_step(params, tok, batch_cache, pos)
+    refs = []
+    for i in range(c):
+        r, batch_cache = m.decode_step(params, tok[:, i:i + 1], batch_cache,
+                                       pos + i)
+        refs.append(r)
+    ref = jnp.concatenate(refs, axis=1)
     table = jnp.asarray(np.stack([alloc.table_row(r, nblk)
                                   for r in (0, 1)]))
-    got, _ = m.decode_step_paged(params, tok, pool, table, pos)
-    assert jnp.array_equal(ref, got), \
-        float(jnp.max(jnp.abs(ref - got)))
+    step = m.decode_step_paged if c == 1 else m.verify_paged
+    got, _ = step(params, tok, pool, table, pos)
+    if use_flash and c > 1:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=1e-5, rtol=0)
+    else:
+        assert jnp.array_equal(ref, got), \
+            float(jnp.max(jnp.abs(ref - got)))
 
 
 def test_chunked_prefill_paged_matches_dense_prefill():
