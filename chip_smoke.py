@@ -104,8 +104,9 @@ def _prefill_into_pool(model, params, prompts, s_max: int, block: int):
     model's own, so the serving engines' compiled programs are reused."""
     import jax
     import jax.numpy as jnp
+    from repro.serving.paged_kv import jit_pool_program
     prefill = jax.jit(model.prefill, static_argnums=2)
-    insert = jax.jit(model.insert_prefill_paged)
+    insert = jit_pool_program(model.insert_prefill_paged)
     b, per = len(prompts), s_max // block
     pool = model.init_paged_cache(b, b * per + 1, block)
     table = 1 + np.arange(b * per, dtype=np.int32).reshape(b, per)

@@ -12,6 +12,7 @@ with it, prefill and decode run the kernels in ``kernels/flash_attention``
 """
 from __future__ import annotations
 
+import math
 from typing import NamedTuple, Optional
 
 import jax
@@ -32,12 +33,13 @@ class KVCache(NamedTuple):
 
 
 class PagedKVCache(NamedTuple):
-    """Shared physical block pool: logical slot ``s`` of a request lives at
-    ``pool[table[s // bs], s % bs]`` where ``table`` is the request's block
-    table (``serving.paged_kv`` owns the accounting; block 0 is the write
-    sink for empty batch slots and is always masked)."""
-    k: jax.Array   # [num_blocks, block_size, kvH, hd]
-    v: jax.Array   # [num_blocks, block_size, kvH, hd]
+    """Shared physical block pool of every layer: logical slot ``s`` of a
+    request lives at ``pool[layer, table[s // bs], s % bs]`` where
+    ``table`` is the request's block table (``serving.paged_kv`` owns the
+    accounting; block 0 is the write sink for empty batch slots and is
+    always masked)."""
+    k: jax.Array   # [L, num_blocks, block_size, kvH, hd]
+    v: jax.Array   # [L, num_blocks, block_size, kvH, hd]
 
 
 class LatentKVCache(NamedTuple):
@@ -49,8 +51,14 @@ class LatentKVCache(NamedTuple):
 class LatentPagedCache(NamedTuple):
     """MLA's paged pool: blocks of latents, addressed like
     :class:`PagedKVCache`; the values are the latent's first
-    ``kv_lora_rank`` entries, so there is no separate V leaf."""
-    c: jax.Array   # [num_blocks, block_size, kv_lora_rank + qk_rope_head_dim]
+    ``kv_lora_rank`` entries, so there is no separate V leaf.  A block's
+    ``block_size`` latents are stored flattened, in rows of 128 values
+    (the TPU's lanes; of fewer where the block's size is not a multiple
+    of 128): 576 values are not a multiple of 128, and a
+    ``[.., block_size, 576]`` pool is laid out with the blocks on the
+    lanes, which every program relays out (copies) whole, while in rows
+    of 128 a block is one contiguous, unpadded piece of memory."""
+    c: jax.Array   # [L, num_blocks, block_size * (r + rope) // 128, 128]
 
 
 def init_attention(key, cfg: ModelConfig, dtype=jnp.bfloat16) -> dict:
@@ -271,36 +279,57 @@ def attention_decode(p: dict, x: jax.Array, cache: KVCache, pos: jax.Array,
     return y, KVCache(k_cache, v_cache)
 
 
-def _paged_write(pool: jax.Array, table: jax.Array, rows: jax.Array,
-                 new: jax.Array) -> jax.Array:
-    """Scatter ``new`` [B, c, ...] into ``pool`` [num_blocks, bs, ...] at
-    absolute positions ``rows`` [B, c]: ring slot ``s = rows % cap`` of
-    sequence ``b`` lives at ``pool[table[b, s // bs], s % bs]`` (empty batch
-    slots' table rows point at the sink)."""
-    bs = pool.shape[1]
+def _paged_write(pool: jax.Array, layer, table: jax.Array,
+                 rows: jax.Array, new: jax.Array) -> jax.Array:
+    """Scatter ``new`` [B, c, ...] into layer ``layer`` of the stacked
+    ``pool`` [L, num_blocks, ...] at absolute positions ``rows`` [B, c]:
+    ring slot ``s = rows % cap`` of sequence ``b`` lives at
+    ``pool[layer, table[b, s // bs], s % bs]`` (empty batch slots' table
+    rows point at the sink).  A block holds ``bs`` token rows of
+    ``new``'s trailing shape, stored as they are or flattened
+    (:class:`LatentPagedCache`)."""
+    row = new.shape[2:]
+    bs = math.prod(pool.shape[2:]) // math.prod(row)
     slot = rows % (table.shape[1] * bs)
-    blk = jnp.take_along_axis(table, slot // bs, axis=1)
-    return pool.at[blk.reshape(-1), (slot % bs).reshape(-1)].set(
-        new.reshape(-1, *pool.shape[2:]).astype(pool.dtype))
+    blk = jnp.take_along_axis(table, slot // bs, axis=1).reshape(-1)
+    off = (slot % bs).reshape(-1)
+    at = (jnp.broadcast_to(layer, blk.shape), blk)
+    new = new.reshape(-1, *row).astype(pool.dtype)
+    if pool.shape[3:] == row:
+        return pool.at[at + (off,)].set(new)
+    # A flattened block: zero the token's segment of it, then add the token
+    # in.  Both are scatters of whole blocks; one of the segment alone
+    # compiles to a serial loop over the rows.  The values are exact
+    # (``x * 1``, ``0 + x``), and two rows never share a segment except in
+    # the sink.
+    seg = jnp.arange(math.prod(pool.shape[2:]))[None, :] // row[0] \
+        == off[:, None]
+    blocks = (-1,) + pool.shape[2:]
+    pool = pool.at[at].multiply((~seg).astype(pool.dtype).reshape(blocks))
+    return pool.at[at].add(
+        jnp.where(seg, jnp.tile(new, (1, bs)), 0).reshape(blocks))
 
 
-def _paged_view(pool: jax.Array, table: jax.Array) -> jax.Array:
-    """Gather each sequence's logical view [B, cap, ...] of ``pool``."""
-    b, n = table.shape
-    return pool[table].reshape(b, n * pool.shape[1], *pool.shape[2:])
+def _paged_view(pool: jax.Array, layer, table: jax.Array,
+                row: tuple) -> jax.Array:
+    """Gather each sequence's logical view [B, cap, *row] of layer
+    ``layer`` of ``pool``."""
+    return pool[layer, table].reshape(table.shape[0], -1, *row)
 
 
-def attention_paged(p: dict, x: jax.Array, cache: PagedKVCache,
+def attention_paged(p: dict, x: jax.Array, cache: PagedKVCache, layer,
                     table: jax.Array, pos: jax.Array,
                     cfg: ModelConfig) -> tuple[jax.Array, PagedKVCache]:
     """``c`` tokens per sequence at absolute positions ``pos[b] ..
     pos[b]+c-1``, each batch row reading and writing K/V through its own
-    block table over the shared physical pool.  x: [B, c, D]; table:
-    [B, max_blocks] int32 physical block ids (logical block ``j`` of
-    sequence ``b`` at ``table[b, j]``; unallocated entries point at the sink
-    block, whose contents are never unmasked); pos: [] or [B].  Decode is
-    ``c == 1``, speculative verify ``c > 1`` over B rows, a prompt chunk
-    ``B == 1``.
+    block table over layer ``layer`` of the shared, layer-stacked physical
+    pool.  x: [B, c, D]; table: [B, max_blocks] int32 physical block ids
+    (logical block ``j`` of sequence ``b`` at ``table[b, j]``; unallocated
+    entries point at the sink block, whose contents are never unmasked);
+    pos: [] or [B].  Decode is ``c == 1``, speculative verify ``c > 1``
+    over B rows, a prompt chunk ``B == 1``.  Returns the whole pool with
+    this layer's rows written: carried through the layer scan and donated
+    by the engine, it is updated where it lies.
 
     At ``c == 1`` the gathered logical view has the width, mask and values
     of :func:`attention_decode` over a contiguous cache of capacity
@@ -318,11 +347,13 @@ def attention_paged(p: dict, x: jax.Array, cache: PagedKVCache,
     pos_vec = jnp.broadcast_to(jnp.asarray(pos).reshape(-1), (b,))
     rows = pos_vec[:, None] + jnp.arange(c, dtype=pos_vec.dtype)   # [B, c]
     if cfg.is_mla:
-        return _mla_paged(p, x, cache, table, rows, cfg)
+        return _mla_paged(p, x, cache, layer, table, rows, cfg)
     q, k_new, v_new = _project_qkv(p, x, cfg, rows)
-    k_pool = _paged_write(cache.k, table, rows, k_new)
-    v_pool = _paged_write(cache.v, table, rows, v_new)
-    k_log, v_log = _paged_view(k_pool, table), _paged_view(v_pool, table)
+    k_pool = _paged_write(cache.k, layer, table, rows, k_new)
+    v_pool = _paged_write(cache.v, layer, table, rows, v_new)
+    row = k_new.shape[2:]
+    k_log = _paged_view(k_pool, layer, table, row)
+    v_log = _paged_view(v_pool, layer, table, row)
     if c == 1:
         out = _attend_decode(q, k_log, v_log, rows[:, 0], cfg)
     else:
@@ -344,12 +375,18 @@ def init_kv_cache(cfg: ModelConfig, batch: int, s_max: int,
     return KVCache(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
 
-def init_paged_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
-                        dtype=jnp.bfloat16):
+def init_paged_kv_cache(cfg: ModelConfig, num_layers: int, num_blocks: int,
+                        block_size: int, dtype=jnp.bfloat16):
+    """The zero pool of ``num_layers`` layers (:class:`PagedKVCache`, or
+    :class:`LatentPagedCache` for MLA)."""
     if cfg.is_mla:
+        # a block's latents, flattened, in rows of 128 values
+        flat = block_size * cfg.mla_latent_width
+        lanes = math.gcd(flat, 128)
         return LatentPagedCache(jnp.zeros(
-            (num_blocks, block_size, cfg.mla_latent_width), dtype))
-    shape = (num_blocks, block_size, cfg.num_kv_heads, cfg.resolved_head_dim)
+            (num_layers, num_blocks, flat // lanes, lanes), dtype))
+    shape = (num_layers, num_blocks, block_size, cfg.num_kv_heads,
+             cfg.resolved_head_dim)
     return PagedKVCache(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
 
@@ -476,7 +513,7 @@ def _mla_decode(p: dict, x: jax.Array, cache: LatentKVCache, pos,
     return y, LatentKVCache(c)
 
 
-def _mla_paged(p: dict, x: jax.Array, cache: LatentPagedCache,
+def _mla_paged(p: dict, x: jax.Array, cache: LatentPagedCache, layer,
                table: jax.Array, rows: jax.Array, cfg: ModelConfig):
     """:func:`attention_paged` over the latent pool, at positions ``rows``
     [B, c]: absorbed decode at ``c == 1``; at ``c > 1`` (a prompt chunk)
@@ -485,8 +522,8 @@ def _mla_paged(p: dict, x: jax.Array, cache: LatentPagedCache,
     with jax.named_scope("mla"):
         b, c, _ = x.shape
         q_nope, q_rope, lat = _mla_project(p, x, cfg, rows)
-        pool = _paged_write(cache.c, table, rows, lat)
-        view = _paged_view(pool, table)
+        pool = _paged_write(cache.c, layer, table, rows, lat)
+        view = _paged_view(pool, layer, table, lat.shape[2:])
         if c == 1:
             y = _mla_attend_latents(p, q_nope, q_rope, view, rows[:, 0], cfg)
         else:

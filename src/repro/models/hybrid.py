@@ -251,27 +251,25 @@ def init_hybrid_paged_cache(cfg: ModelConfig, batch: int, num_blocks: int,
     n_sb = cfg.num_layers // sb
     n_mamba = sb - 1
     dt = dtype_of(cfg)
-    one = init_paged_kv_cache(cfg, num_blocks, block_size, dt)
-    def rep(a):
-        return jnp.broadcast_to(a[None], (n_sb,) + a.shape).copy()
     return HybridPagedCache(
-        kv=PagedKVCache(rep(one.k), rep(one.v)),
+        kv=init_paged_kv_cache(cfg, n_sb, num_blocks, block_size, dt),
         conv=jnp.zeros((n_sb, n_mamba, batch, cfg.mamba_d_conv - 1,
                         cfg.mamba_d_inner), dt),
         h=jnp.zeros((n_sb, n_mamba, batch, cfg.mamba_d_inner,
                      cfg.mamba_d_state), jnp.float32))
 
 
-def _superblock_decode_paged(p: dict, x, kv: PagedKVCache, conv, h, table,
-                             pos, cfg: ModelConfig):
-    new_kv = kv
+def _superblock_decode_paged(p: dict, x, kv: PagedKVCache, sb, conv, h,
+                             table, pos, cfg: ModelConfig):
+    """Superblock ``sb``: its attention layer reads and writes layer ``sb``
+    of the stacked pool ``kv``."""
     new_conv, new_h = [], []
     mi = 0
     for layer in p["layers"]:
         z = rms_norm(layer["ln1"], x, cfg.norm_eps)
         if "attn" in layer:
-            y, new_kv = attention_paged(layer["attn"], z, kv, table, pos,
-                                        cfg)
+            y, kv = attention_paged(layer["attn"], z, kv, sb, table, pos,
+                                    cfg)
             x = x + y
         else:
             st = MambaState(conv=conv[mi], h=h[mi])
@@ -281,28 +279,31 @@ def _superblock_decode_paged(p: dict, x, kv: PagedKVCache, conv, h, table,
             mi += 1
             x = x + y
         x, _ = _ffn(layer, x, cfg)
-    return x, new_kv, jnp.stack(new_conv), jnp.stack(new_h)
+    return x, kv, jnp.stack(new_conv), jnp.stack(new_h)
 
 
 def hybrid_decode_step_paged(params: dict, token: jax.Array,
                              cache: HybridPagedCache, table: jax.Array,
                              pos, cfg: ModelConfig):
     """Paged hybrid decode: attention KV read through ``table``
-    [B, max_blocks]; conv/ssm states indexed by batch slot as before."""
+    [B, max_blocks] from the stacked pool, which the scan carries (as
+    ``transformer._scan_stacks`` does); conv/ssm states indexed by batch
+    slot as before."""
     x = embed(params["embed"], token, cfg.onehot_embed)
 
-    def body(hx, layer):
-        pl, kv_k, kv_v, conv, h = layer
+    def body(carry, layer):
+        hx, kv = carry
+        pl, sb, conv, h = layer
         y, kv, conv2, h2 = _superblock_decode_paged(
-            pl, hx, PagedKVCache(kv_k, kv_v), conv, h, table, pos, cfg)
-        return y, (kv, conv2, h2)
+            pl, hx, kv, sb, conv, h, table, pos, cfg)
+        return (y, kv), (conv2, h2)
 
-    x, (kv, conv, h) = jax.lax.scan(
-        body, x, (params["superblocks"], cache.kv.k, cache.kv.v,
-                  cache.conv, cache.h), unroll=cfg.unroll_scans)
+    (x, kv), (conv, h) = jax.lax.scan(
+        body, (x, cache.kv), (params["superblocks"],
+                              jnp.arange(cache.conv.shape[0]),
+                              cache.conv, cache.h), unroll=cfg.unroll_scans)
     x = rms_norm(params["ln_f"], x, cfg.norm_eps)
-    return linear(params["lm_head"], x), HybridPagedCache(
-        PagedKVCache(kv.k, kv.v), conv, h)
+    return linear(params["lm_head"], x), HybridPagedCache(kv, conv, h)
 
 
 def hybrid_insert_prefill_paged(cache: HybridPagedCache, dense: HybridCache,

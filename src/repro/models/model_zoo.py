@@ -27,7 +27,10 @@ class Model:
     #: the hybrid overrides it (KV on axis 1, Mamba states on axis 2).
     insert_prefill: Optional[Callable[..., Any]] = None
     # Paged-KV serving paths (None where the family has no paged form —
-    # SSM/enc-dec fall back to the contiguous engine):
+    # SSM/enc-dec fall back to the contiguous engine).  Each takes the
+    # layer-stacked pool as its argument named ``cache`` and returns it
+    # updated; the engine donates that argument by name
+    # (``serving.paged_kv.jit_pool_program``):
     #   init_paged_cache(batch, num_blocks, block_size) -> pool cache
     #   decode_step_paged(params, token, cache, table, pos)
     #   insert_prefill_paged(cache, dense_cache_B1, table_row, slot)

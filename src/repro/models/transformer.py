@@ -19,9 +19,9 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
-from .attention import (KVCache, LatentPagedCache, attention_decode,
-                        attention_fwd, attention_paged, init_attention,
-                        init_kv_cache, init_paged_kv_cache)
+from .attention import (KVCache, attention_decode, attention_fwd,
+                        attention_paged, init_attention, init_kv_cache,
+                        init_paged_kv_cache)
 from .layers import (dtype_of, embed, init_embedding, init_linear,
                      init_mlp, init_rms_norm, linear, mlp, rms_norm)
 from .moe import MoEStats, init_moe, moe_fwd
@@ -87,17 +87,21 @@ def _stacked(cfg: ModelConfig, one) -> list:
 
 def _scan_stacks(params: dict, x: jax.Array, cache, cfg: ModelConfig,
                  layer):
-    """Scan each stack's layers over ``x`` and the stack's cache:
-    ``layer(p, h, cache_l, moe) -> (h, new cache_l)``.  Returns the last
-    hidden state and the new cache."""
+    """Scan each stack's layers over ``x``, carrying the stack's whole
+    layer-stacked cache: ``layer(p, h, cache, l, moe) -> (h, cache)``
+    reads and writes layer ``l`` of it.  A carried cache that the caller
+    donates is updated where it lies; as a scanned input and output it
+    would be sliced out and stacked anew, a copy of the whole cache per
+    call.  Returns the last hidden state and the new cache."""
     new = []
-    for (name, _, moe), c in zip(_stacks(cfg), _split(cache, cfg)):
-        def body(h, pc, moe=moe):
-            return layer(pc[0], h, pc[1], moe)
+    for (name, n, moe), c in zip(_stacks(cfg), _split(cache, cfg)):
+        def body(hc, pl, moe=moe):
+            h, c = layer(pl[0], hc[0], hc[1], pl[1], moe)
+            return (h, c), None
 
-        x, nc = jax.lax.scan(body, x, (params[name], c),
-                             unroll=cfg.unroll_scans)
-        new.append(nc)
+        (x, c), _ = jax.lax.scan(body, (x, c), (params[name], jnp.arange(n)),
+                                 unroll=cfg.unroll_scans)
+        new.append(c)
     return x, _join(new, cfg)
 
 
@@ -138,12 +142,16 @@ def _block_fwd(p: dict, x: jax.Array, cfg: ModelConfig, positions, mask,
     return out, stats
 
 
-def _block_decode(p: dict, x: jax.Array, cache: KVCache, pos, cfg, moe):
-    y_attn, new_cache = attention_decode(
-        p["attn"], rms_norm(p["ln1"], x, cfg.norm_eps), cache, pos, cfg)
+def _block_decode(p: dict, x: jax.Array, cache: KVCache, layer, pos, cfg,
+                  moe):
+    """One block's decode against layer ``layer`` of the stacked
+    contiguous ``cache``."""
+    y_attn, new = attention_decode(
+        p["attn"], rms_norm(p["ln1"], x, cfg.norm_eps),
+        jax.tree.map(lambda a: a[layer], cache), pos, cfg)
     h = x + y_attn
     y, _ = _ffn(p, rms_norm(p["ln2"], h, cfg.norm_eps), cfg, moe)
-    return h + y, new_cache
+    return h + y, jax.tree.map(lambda a, n: a.at[layer].set(n), cache, new)
 
 
 def init_lm(key, cfg: ModelConfig) -> dict:
@@ -262,7 +270,7 @@ def lm_decode_step(params: dict, token: jax.Array, cache,
     x = embed(params["embed"], token, cfg.onehot_embed)
     x, new_cache = _scan_stacks(
         params, x, cache, cfg,
-        lambda pl, h, cl, moe: _block_decode(pl, h, cl, pos, cfg, moe))
+        lambda pl, h, c, l, moe: _block_decode(pl, h, c, l, pos, cfg, moe))
     x = rms_norm(params["ln_f"], x, cfg.norm_eps)
     return _unembed(params, x, cfg), new_cache
 
@@ -272,16 +280,12 @@ def lm_decode_step(params: dict, token: jax.Array, cache,
 # --------------------------------------------------------------------------
 
 def init_lm_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int):
-    """Layer-stacked physical block pool [L, num_blocks, bs, ...]; the
-    block table (host-side, ``serving.paged_kv``) is shared across layers —
-    block id ``b`` names row ``b`` of every layer's pool."""
-    if cfg.is_mla:
-        # zeros of the stacked shape: no one-layer copy to broadcast
-        shape = (num_blocks, block_size, cfg.mla_latent_width)
-        return _join([LatentPagedCache(jnp.zeros((n,) + shape, dtype_of(cfg)))
-                      for _, n, _ in _stacks(cfg)], cfg)
-    one = init_paged_kv_cache(cfg, num_blocks, block_size, dtype_of(cfg))
-    return _join(_stacked(cfg, one), cfg)
+    """Layer-stacked physical block pool [L, num_blocks, ...] per stack;
+    the block table (host-side, ``serving.paged_kv``) is shared across
+    layers — block id ``b`` names row ``b`` of every layer's pool."""
+    return _join([init_paged_kv_cache(cfg, n, num_blocks, block_size,
+                                      dtype_of(cfg))
+                  for _, n, _ in _stacks(cfg)], cfg)
 
 
 def lm_paged(params: dict, tokens: jax.Array, cache, table: jax.Array,
@@ -290,7 +294,8 @@ def lm_paged(params: dict, tokens: jax.Array, cache, table: jax.Array,
     ``pos[b] .. pos[b]+c-1`` against the paged pool, K/V read and written
     through ``table`` [B, max_blocks] (:func:`attention_paged`).  Returns
     (logits, new pool): every row's [B, c, V], or with ``last_only`` the
-    last row's [B, 1, V].
+    last row's [B, 1, V].  The layer scan carries the pool, so under a
+    jit that donates it every layer writes its rows in place.
 
     Decode (``c == 1``) is bit-identical (fp32) to :func:`lm_decode_step`
     over a contiguous cache of the same logical capacity.  Speculative
@@ -302,12 +307,12 @@ def lm_paged(params: dict, tokens: jax.Array, cache, table: jax.Array,
     last row, which seeds the first generated token on the final chunk."""
     x = embed(params["embed"], tokens, cfg.onehot_embed)
 
-    def layer(pl, h, cl, moe):
+    def layer(pl, h, pool, l, moe):
         z = rms_norm(pl["ln1"], h, cfg.norm_eps)
-        attn, new_c = attention_paged(pl["attn"], z, cl, table, pos, cfg)
+        attn, pool = attention_paged(pl["attn"], z, pool, l, table, pos, cfg)
         h = h + attn
         y, _ = _ffn(pl, rms_norm(pl["ln2"], h, cfg.norm_eps), cfg, moe)
-        return h + y, new_c
+        return h + y, pool
 
     x, new_cache = _scan_stacks(params, x, cache, cfg, layer)
     x = rms_norm(params["ln_f"], x, cfg.norm_eps)

@@ -47,7 +47,7 @@ from ..core.strategy import MergePolicy
 from ..kernels.flash_attention.decode import decode_kv_dtype, decode_tile
 from ..models.model_zoo import Model
 from .paged_kv import (BlockAllocator, PoolExhausted, SINK_BLOCK,
-                       prefix_block_keys)
+                       jit_pool_program, prefix_block_keys)
 from .speculative import Speculator
 
 __all__ = ["ServingEngine"]
@@ -197,10 +197,12 @@ class ServingEngine:
             self._table_dev = jnp.asarray(self.table)
             self._alloc_seen = self.alloc.version
             self._table_dirty = False
-            self._decode = jax.jit(model.decode_step_paged)
-            self._insert_prefill = jax.jit(model.insert_prefill_paged)
-            self._prefill_chunk = (jax.jit(model.prefill_chunk_paged)
-                                   if model.prefill_chunk_paged else None)
+            self._decode = jit_pool_program(model.decode_step_paged)
+            self._insert_prefill = jit_pool_program(
+                model.insert_prefill_paged)
+            self._prefill_chunk = (
+                jit_pool_program(model.prefill_chunk_paged)
+                if model.prefill_chunk_paged else None)
             # prompts longer than the ring must take the ring-aligning
             # dense prefill (chunks would wrap mid-prompt)
             def _chunk_eligible(r):
@@ -440,7 +442,7 @@ class ServingEngine:
             # victim had a larger ring than ours: the prefix cannot resume
             # chunk-aligned here — recompute through the dense prefill
             return False
-        if kv[0].shape[2] != self.block_size or \
+        if any(b.shape[2:] != a.shape[2:] for a, b in zip(leaves, kv)) or \
                 not self.alloc.can_allocate(nblk * self.block_size,
                                             req.rid):
             return False                     # thief pool full: recompute

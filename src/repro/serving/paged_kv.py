@@ -1,8 +1,10 @@
 """Paged KV cache: host-side block allocator + per-request block tables.
 
-The device side is a shared physical pool of fixed-size KV blocks
-(``[num_blocks, block_size, kvH, hd]`` per layer — see
-``models.attention.PagedKVCache``); this module owns the *accounting*: which
+The device side is a shared physical pool of fixed-size KV blocks, stacked
+over layers (``[L, num_blocks, block_size, kvH, hd]``, or MLA's
+``[L, num_blocks, block_size * latent / 128, 128]`` — see
+``models.attention``), which every program that writes it takes donated
+(:func:`jit_pool_program`); this module owns the *accounting*: which
 physical blocks belong to which request, what is free, what is cached, and
 the padded ``int32`` table rows the decode/prefill kernels gather through.
 
@@ -45,13 +47,23 @@ import hashlib
 from collections import OrderedDict
 from typing import Dict, Hashable, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 __all__ = ["BlockAllocator", "PoolExhausted", "SINK_BLOCK",
-           "prefix_block_keys"]
+           "jit_pool_program", "prefix_block_keys"]
 
 #: physical block id reserved as the write sink for empty decode slots
 SINK_BLOCK = 0
+
+
+def jit_pool_program(fn):
+    """``jax.jit`` a program that takes the pool as its ``cache`` argument
+    and returns it updated (the model's paged decode, prompt chunk, insert
+    and verify).  The pool is donated: the program writes its rows where
+    the pool lies instead of into a fresh copy, and the pool passed in is
+    deleted by the call, so a caller keeps only the one returned."""
+    return jax.jit(fn, donate_argnames="cache")
 
 
 class PoolExhausted(RuntimeError):

@@ -61,7 +61,7 @@ from ..core.strategy import MergePolicy, PriorityStrategy
 from ..core.task import FinishRegion, Task
 from ..core.task_storage import StrategyTaskStorage
 from ..models.model_zoo import Model
-from .paged_kv import SINK_BLOCK
+from .paged_kv import SINK_BLOCK, jit_pool_program
 
 __all__ = ["Speculator", "SpecStrategy", "DraftStrategy", "VerifyStrategy",
            "accept_longest_prefix", "SPEC_METRIC_KEYS", "SPEC_KEY_ARITY"]
@@ -307,7 +307,7 @@ class Speculator:
         s_max = engine.s_max
         self._prefill = jax.jit(
             lambda p, b: self.draft_model.prefill(p, b, s_max))
-        self._verify = jax.jit(engine.model.verify_paged)
+        self._verify = jit_pool_program(engine.model.verify_paged)
         for key in SPEC_METRIC_KEYS:
             engine.batcher.metrics.setdefault(key, 0)
 

@@ -139,15 +139,15 @@ def test_absorbed_decode_equals_expanded_attention():
     cache = A.init_kv_cache(cfg, 1, 32, jnp.float32)
     for flash in (False, True):
         c = cfg.replace(use_flash=flash)
-        pool = A.init_paged_kv_cache(c, 5, 8, jnp.float32)
+        pool = A.init_paged_kv_cache(c, 1, 5, 8, jnp.float32)
         table = jnp.asarray([[3, 1, 4, 2]], jnp.int32)
         dense = cache
         for t in range(s):
             got, dense = A.attention_decode(p, x[:, t:t + 1], dense,
                                             jnp.int32(t), c)
             paged, pool = A.attention_paged(
-                p, x[:, t:t + 1], pool, table, jnp.asarray([t], jnp.int32),
-                c)
+                p, x[:, t:t + 1], pool, 0, table,
+                jnp.asarray([t], jnp.int32), c)
             for y in (got, paged):
                 np.testing.assert_allclose(np.asarray(y[:, 0]),
                                            np.asarray(want[:, t]),

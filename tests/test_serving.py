@@ -203,11 +203,14 @@ def test_paged_pool_pressure_preempts_and_completes():
     assert eng.batcher.metrics["preempted"] > 0
 
 
-def test_paged_kv_migrates_with_stolen_chunk_request():
+@pytest.mark.parametrize("name", ["qwen2-1.5b", "deepseek-v2-lite"],
+                         ids=["gqa", "latent"])
+def test_paged_kv_migrates_with_stolen_chunk_request(name):
     """A partially-prefilled request stolen from one engine resumes on the
     thief from the chunk boundary (prefix KV travels) and generates the
-    same tokens as an undisturbed run."""
-    cfg, model, params = _model()
+    same tokens as an undisturbed run — K/V blocks and the latent pool's
+    flat block rows alike."""
+    cfg, model, params = _model(name)
     rng = np.random.default_rng(14)
     long_p = rng.integers(0, cfg.vocab_size, 40)
     kw = dict(s_max=48, kv_mode="paged", prefill_chunk=8, block_size=8)
@@ -315,6 +318,64 @@ def test_paged_engine_hybrid_family():
                       kv_mode="paged", block_size=8)
     assert got == ref
     assert eng.batcher.prefill_chunk is None   # chunking auto-disabled
+
+
+@pytest.mark.parametrize("name,kw", [
+    ("qwen2-1.5b", dict(prefill_chunk=8)),
+    ("qwen2-1.5b", {}),
+    ("deepseek-v2-lite", dict(prefill_chunk=8)),
+    ("jamba-v0.1-52b", dict(ssm_chunk=4)),
+], ids=["chunk", "whole-prompt", "latent", "hybrid"])
+def test_engine_steps_consume_the_pool_they_update(name, kw):
+    """Every program that writes the pool (prompt chunk, whole-prompt
+    insert, decode) takes it donated: after a step, the pool the engine
+    held before it is deleted and the one it holds is live."""
+    chunk = kw.pop("prefill_chunk", None)
+    cfg, model, params = _model(name, **kw)
+    eng = ServingEngine(model, params, max_batch=2, s_max=32,
+                        kv_mode="paged", block_size=8, prefill_chunk=chunk)
+    rng = np.random.default_rng(5)
+    reqs = [eng.submit(rng.integers(0, cfg.vocab_size, n), 4)
+            for n in (13, 6)]
+    steps = 0
+    while any(r.state.name != "DONE" for r in reqs):
+        before = jax.tree.leaves(eng.cache)
+        eng.step()
+        after = jax.tree.leaves(eng.cache)
+        assert all(a.is_deleted() for a in before)
+        assert not any(a.is_deleted() for a in after)
+        steps += 1
+    assert steps >= 3
+
+
+@pytest.mark.parametrize("block_size", [16, 8])
+def test_latent_pool_forks_and_inserts_after_donated_steps(block_size):
+    """The latent pool keeps a block's latents flattened, in rows of 128
+    values where the block's size allows (16 latents of 40 here), else of
+    fewer: a prefix-cache fork (``_copy_block``) copies the block in
+    every layer of both stacks, and whole-prompt prefill scatters a prompt
+    into it (``insert_prefill``); both on a pool that donated steps have
+    already updated, and the tokens match the contiguous engine's."""
+    cfg, model, params = _model("deepseek-v2-lite", dtype="float32",
+                                param_dtype="float32")
+    rng = np.random.default_rng(9)
+    prompts = [rng.integers(0, cfg.vocab_size, n) for n in (19, 7)]
+    ref, _ = _drain(model, params, prompts, max_batch=2, s_max=32,
+                    kv_mode="contiguous")
+    got, eng = _drain(model, params, prompts, max_batch=2, s_max=32,
+                      kv_mode="paged", block_size=block_size)
+    assert got == ref
+    assert eng.batcher.metrics["decode_host_reads"] > 0
+    flat = block_size * cfg.mla_latent_width
+    lanes = 128 if flat % 128 == 0 else 64
+    assert all(a.shape[1:] == (eng.alloc.total_blocks + 1, flat // lanes,
+                               lanes) for a in jax.tree.leaves(eng.cache))
+    src = [np.asarray(a[:, 1]) for a in jax.tree.leaves(eng.cache)]
+    assert any(np.abs(x).max() > 0 for x in src)     # written by the run
+    eng._copy_block(1, 2)
+    for a, x in zip(jax.tree.leaves(eng.cache), src):
+        np.testing.assert_array_equal(np.asarray(a[:, 2]), x)
+        np.testing.assert_array_equal(np.asarray(a[:, 1]), x)
 
 
 def test_admission_rejects_ring_wrapping_requests():

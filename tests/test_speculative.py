@@ -98,6 +98,25 @@ def test_cross_draft_bit_identical(dense):
     eng.alloc.check()
 
 
+def test_verify_consumes_the_pool(dense):
+    """The verify program takes the pool donated, as decode does: after
+    every step the pool held before it is deleted, and the tokens are
+    those of plain decode."""
+    cfg, model, params = dense
+    prompts = _prompts(cfg)
+    _, base = _run(model, params, prompts)
+    eng = ServingEngine(model, params, max_batch=3, s_max=48,
+                        speculator=Speculator(model, params, k=3))
+    reqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
+    while any(r.state.name != "DONE" for r in reqs):
+        before = jax.tree.leaves(eng.cache)
+        eng.step()
+        assert all(a.is_deleted() for a in before)
+        assert not any(a.is_deleted() for a in jax.tree.leaves(eng.cache))
+    assert eng.batcher.metrics["spec_verify_calls"] > 0
+    assert [eng.outputs[r.rid] for r in reqs] == base
+
+
 def test_spec_with_prefix_cache_warm(dense):
     """Speculation over COW-shared prefix blocks: the reserve path must
     fork before writing, never corrupting published blocks."""

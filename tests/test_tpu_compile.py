@@ -8,6 +8,9 @@ a program that does not fit the device).  Interpret-mode tests cannot see
 any of that.  The topology is described inside a fixture, never at import
 time: only one process at a time may load the TPU library.
 """
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -19,6 +22,7 @@ from repro.kernels.mla_decode.ops import mla_decode
 from repro.kernels.moe_gmm.ops import grouped_swiglu
 from repro.kernels.prefix_scan.ops import prefix_scan
 from repro.kernels.wkv6.ops import wkv6
+from repro.serving.paged_kv import jit_pool_program
 
 #: v5e high-bandwidth memory per chip (Google Cloud, "TPU v5e")
 V5E_HBM_BYTES = 16 * 1024 ** 3
@@ -53,6 +57,7 @@ def _compile(fn, *args):
 
 
 QWEN2 = get_config("qwen2-1.5b")
+DEEPSEEK = get_config("deepseek-v2-lite")
 
 
 @pytest.mark.parametrize("s", [256, 1024])
@@ -109,91 +114,121 @@ def test_prefix_scan_compiles(spec, dtype):
              spec((3, 5000), dtype))
 
 
-def test_qwen2_paged_decode_step_compiles(spec, one_chip, monkeypatch):
-    """One full-width qwen2-1.5b paged decode step with kernels on, at the
-    chip smoke's serving shape: B=8, 2048-token rings of 16-token blocks."""
-    from repro.models import build_model
-    # the kernels pick compiled-vs-interpret from the default backend,
-    # which is the CPU here: steer them to the chip this compile is for
+@pytest.fixture
+def chip_kernels(monkeypatch):
+    """The kernels pick compiled-vs-interpret from the default backend,
+    which is the CPU here: steer them to the chip these compiles are for."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     jax.clear_caches()
-    model = build_model(QWEN2.replace(use_flash=True))
-    b, s_max, bs = 8, 2048, 16
-    nblk = b * s_max // bs + 1
+    yield
+    jax.clear_caches()
+
+
+def _paged(spec, cfg, b: int, s_max: int, bs: int = 16):
+    """(model with kernels on, its parameters, its pool) for ``b`` rows of
+    ``s_max``-token rings of ``bs``-token blocks, as shapes on the chip."""
+    from repro.models import build_model
+    model = build_model(cfg.replace(use_flash=True))
 
     def on_chip(tree):
         return jax.tree.map(lambda a: spec(a.shape, a.dtype), tree)
 
     params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    cache = on_chip(jax.eval_shape(
-        lambda: model.init_paged_cache(b, nblk, bs)))
-    compiled = _compile(model.decode_step_paged, params,
-                        spec((b, 1), jnp.int32), cache,
-                        spec((b, s_max // bs), jnp.int32),
-                        spec((b,), jnp.int32))
-    jax.clear_caches()
+    pool = on_chip(jax.eval_shape(
+        lambda: model.init_paged_cache(b, b * s_max // bs + 1, bs)))
+    return model, params, pool
+
+
+def _compile_pool_program(spec, cfg, program: str, b: int, s_max: int,
+                          bs: int = 16, chunk: int = 512):
+    """Compile the model's ``program`` the way the serving engine jits it
+    (the pool donated): a decode step over ``b`` rows, or one
+    ``chunk``-token prompt chunk.  Returns (compiled, pool)."""
+    model, params, pool = _paged(spec, cfg, b, s_max, bs)
+    if program == "decode_step_paged":
+        args = (params, spec((b, 1), jnp.int32), pool,
+                spec((b, s_max // bs), jnp.int32), spec((b,), jnp.int32))
+    else:
+        args = (params, {"tokens": spec((1, chunk), jnp.int32)}, pool,
+                spec((s_max // bs,), jnp.int32), spec((), jnp.int32))
+    compiled = jit_pool_program(getattr(model, program)).lower(
+        *args).compile()
+    return compiled, pool
+
+
+def _live_bytes(compiled) -> int:
+    """Device bytes live while the program runs: arguments, outputs and
+    temporaries, with each output that reuses a donated argument's buffer
+    counted once."""
     mem = compiled.memory_analysis()
-    # the output pool is a fresh buffer: arguments, output and temporaries
-    # are all live while the step runs
-    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-            + mem.temp_size_in_bytes)
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+
+
+_HLO_VALUE = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(([^)]*)\)")
+_MOVES = ("copy", "dynamic-slice", "dynamic-update-slice")
+
+
+def _pool_moves(text: str, pool) -> list:
+    """The instructions of ``text`` that copy, slice out or write back a
+    whole stacked pool or one layer of it: a ``copy`` or
+    ``dynamic-slice`` (or a fusion named after one, or after
+    ``dynamic-update-slice``) of such a shape, or a
+    ``dynamic-update-slice`` whose update is a layer's size or more.  A
+    smaller update writes rows where the pool lies."""
+    shapes = set()
+    for a in jax.tree.leaves(pool):
+        shapes |= {a.shape, a.shape[1:], (1,) + a.shape[1:]}
+    layer = min(math.prod(a.shape[1:]) for a in jax.tree.leaves(pool))
+    values, moves = {}, []
+    for line in text.splitlines():
+        m = _HLO_VALUE.match(line)
+        if not m:
+            continue
+        name, dims, op, operands = m.groups()
+        shape = tuple(int(d) for d in dims.split(",") if d)
+        values[name] = shape
+        if op == "dynamic-update-slice":
+            update = values.get(operands.split(", ")[1].lstrip("%"))
+            if update is None or math.prod(update) >= layer:
+                moves.append(line.strip()[:160])
+        elif shape in shapes and any(w in op or w in name for w in _MOVES):
+            moves.append(line.strip()[:160])
+    return moves
+
+
+def test_qwen2_paged_decode_step_compiles(spec, chip_kernels):
+    """One full-width qwen2-1.5b paged decode step with kernels on, at the
+    chip smoke's serving shape: B=8, 2048-token rings of 16-token blocks."""
+    compiled, _ = _compile_pool_program(spec, QWEN2, "decode_step_paged", 8,
+                                        2048)
+    assert "tpu_custom_call" in compiled.as_text()
+    live = _live_bytes(compiled)
     assert live < V5E_HBM_BYTES, live
 
 
-def test_deepseek_v2_lite_paged_decode_step_compiles(spec, monkeypatch):
+def test_deepseek_v2_lite_paged_decode_step_compiles(spec, chip_kernels):
     """One deepseek-v2-lite paged decode step with kernels on, at the
     longgen cell's serving shape: 32 rows, 4096-token rings of 16-token
     blocks over the latent pool, 8 held experts of 64."""
-    from repro.models import build_model
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    jax.clear_caches()
-    model = build_model(get_config("deepseek-v2-lite").replace(
-        use_flash=True))
-    b, s_max, bs = 32, 4096, 16
-    nblk = b * s_max // bs + 1
-
-    def on_chip(tree):
-        return jax.tree.map(lambda a: spec(a.shape, a.dtype), tree)
-
-    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    cache = on_chip(jax.eval_shape(
-        lambda: model.init_paged_cache(b, nblk, bs)))
-    compiled = _compile(model.decode_step_paged, params,
-                        spec((b, 1), jnp.int32), cache,
-                        spec((b, s_max // bs), jnp.int32),
-                        spec((b,), jnp.int32))
-    jax.clear_caches()
+    compiled, _ = _compile_pool_program(spec, DEEPSEEK, "decode_step_paged",
+                                        32, 4096)
     text = compiled.as_text()
     assert "mla_decode_pallas" in text and "grouped_swiglu_pallas" in text
-    mem = compiled.memory_analysis()
-    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-            + mem.temp_size_in_bytes)
+    live = _live_bytes(compiled)
     assert live < V5E_HBM_BYTES, live
 
 
-def test_qwen2_longgen_decode_step_runs_the_decode_kernel(spec, monkeypatch):
+def test_qwen2_longgen_decode_step_runs_the_decode_kernel(spec,
+                                                          chip_kernels):
     """One full-width qwen2-1.5b paged decode step at the longgen cell's
     shape (32 rows, 4096-token rings of 16-token blocks) runs the flash
     decode kernel under a name the roofline reader matches, hands it the
     gathered K/V without a relayout copy, and fits one chip."""
-    from repro.models import build_model
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    jax.clear_caches()
-    model = build_model(QWEN2.replace(use_flash=True))
-    b, s_max, bs = 32, 4096, 16
-    nblk = b * s_max // bs + 1
-
-    def on_chip(tree):
-        return jax.tree.map(lambda a: spec(a.shape, a.dtype), tree)
-
-    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    cache = on_chip(jax.eval_shape(
-        lambda: model.init_paged_cache(b, nblk, bs)))
-    compiled = _compile(model.decode_step_paged, params,
-                        spec((b, 1), jnp.int32), cache,
-                        spec((b, s_max // bs), jnp.int32),
-                        spec((b,), jnp.int32))
-    jax.clear_caches()
+    b, s_max = 32, 4096
+    compiled, _ = _compile_pool_program(spec, QWEN2, "decode_step_paged", b,
+                                        s_max)
     text = compiled.as_text()
     kernel = "flash_attention_pallas_decode"
     # bench/metrics/flash_decode_roofline.longgen.py matches this substring
@@ -202,7 +237,32 @@ def test_qwen2_longgen_decode_step_runs_the_decode_kernel(spec, monkeypatch):
     view = f"bf16[{b},{s_max * QWEN2.num_kv_heads},{QWEN2.head_dim}]"
     made = [ln for ln in text.splitlines() if f"= {view}" in ln]
     assert made and all(" bitcast(" in ln for ln in made), made
-    mem = compiled.memory_analysis()
-    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-            + mem.temp_size_in_bytes)
+    live = _live_bytes(compiled)
     assert live < V5E_HBM_BYTES, live
+
+
+@pytest.mark.parametrize("program", ["decode_step_paged",
+                                     "prefill_chunk_paged"])
+@pytest.mark.parametrize("cfg", [QWEN2, DEEPSEEK], ids=lambda c: c.name)
+def test_pool_programs_write_the_pool_in_place(spec, chip_kernels, cfg,
+                                               program):
+    """The longgen cells' decode step (32 rows, 4096-token rings of
+    16-token blocks) and 512-token prompt chunk, jitted as the engine jits
+    them: the output pool is the donated input's buffer, and no
+    instruction copies the pool, slices a layer out of it or writes a
+    layer back.  Temporaries hold a layer's gathered view (for the latent
+    pool, also its relayout to the kernel's ``[B, cap, 576]``) and a
+    chunk's attention scores, each at most one layer's pool in size: all
+    of them stay below three layers' pools."""
+    compiled, pool = _compile_pool_program(spec, cfg, program, 32, 4096)
+    leaves = jax.tree.leaves(pool)
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in leaves)
+    layer_bytes = sum(a.size // a.shape[0] * a.dtype.itemsize
+                      for a in leaves if a.shape[0] > 1)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes, (mem.alias_size_in_bytes,
+                                                  pool_bytes)
+    moves = _pool_moves(compiled.as_text(), pool)
+    assert not moves, moves
+    assert mem.temp_size_in_bytes < 3 * layer_bytes, (
+        mem.temp_size_in_bytes, layer_bytes)
